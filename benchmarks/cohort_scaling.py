@@ -31,6 +31,7 @@ import numpy as np
 from repro.core.protocol import ProtocolConfig
 from repro.data import federated, synthetic
 from repro.fl import AsyncConfig, EngineConfig, FederatedEngine
+from repro.launch.cache import enable_compile_cache
 from repro.models import cnn
 
 from _harness import steady_round_s as _steady_s, write_report
@@ -140,6 +141,7 @@ def bench_async(num_clients: int = 32, concurrency: int = 16,
 
 
 def main():
+    enable_compile_cache()
     ap = argparse.ArgumentParser(description=__doc__)
     ap.add_argument("--smoke", action="store_true",
                     help="cohorts 4/8 and fewer rounds (CI)")

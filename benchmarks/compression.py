@@ -39,6 +39,7 @@ from repro.core import sparsify as sparsify_lib
 from repro.core.fsfl import run_federated
 from repro.core.protocol import ProtocolConfig
 from repro.data import federated, synthetic
+from repro.launch.cache import enable_compile_cache
 from repro.models import cnn
 
 
@@ -355,6 +356,7 @@ def _print_rows(rows):
 
 
 def main():
+    enable_compile_cache()
     ap = argparse.ArgumentParser(description=__doc__)
     ap.add_argument("--smoke", action="store_true",
                     help="codec-registry ladder only, tiny model (CI)")

@@ -30,6 +30,7 @@ from repro.core.protocol import ProtocolConfig, ServerState
 from repro.core import quant as quant_lib
 from repro.fl import EngineConfig, Uplink, run_scenario
 from repro.comms import ClientUpdate
+from repro.launch.cache import enable_compile_cache
 
 from _harness import time_best, write_report
 
@@ -237,6 +238,7 @@ def bench_rounds(rounds: int, scenarios=("sync_full_fedavg_fsfl",
 
 
 def main():
+    enable_compile_cache()
     ap = argparse.ArgumentParser(description=__doc__)
     ap.add_argument("--smoke", action="store_true",
                     help="small tensors + 1 round per scenario (CI)")

@@ -23,6 +23,7 @@ from repro.core.fsfl import run_federated
 from repro.core.protocol import baseline_configs
 from repro.data import federated, synthetic
 from repro.fl import list_scenarios, run_scenario
+from repro.launch.cache import enable_compile_cache
 from repro.models import cnn
 
 
@@ -109,6 +110,7 @@ def _print_rows(rows):
 
 
 def main():
+    enable_compile_cache()
     ap = argparse.ArgumentParser(description=__doc__)
     ap.add_argument("--scenarios", nargs="*", metavar="NAME",
                     help="run named engine scenarios instead of the Table-2 "

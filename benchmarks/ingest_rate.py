@@ -37,6 +37,7 @@ import numpy as np
 
 from repro import comms
 from repro.fl.ingest import IngestConfig
+from repro.launch.cache import enable_compile_cache
 from repro.launch.ingest_serve import serve_cohort, synthetic_cohort
 
 DENSITY = 0.04      # sparsity 0.96 — the regime the speculative decoder
@@ -126,6 +127,7 @@ def run(guard: bool = False, smoke: bool = False) -> dict:
 
 
 def main():
+    enable_compile_cache()
     ap = argparse.ArgumentParser(description=__doc__)
     ap.add_argument("--smoke", action="store_true",
                     help="fewer reps (CI)")

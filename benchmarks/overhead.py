@@ -12,6 +12,7 @@ import jax
 import jax.numpy as jnp
 
 from repro.core import scaling as scaling_lib
+from repro.launch.cache import enable_compile_cache
 from repro.models import cnn
 
 
@@ -91,6 +92,7 @@ def transformer_scale_counts():
 
 
 def main():
+    enable_compile_cache()
     rows = [measure(cnn.mobilenetv2_small(num_classes=4)),
             measure(cnn.resnet18_small(num_classes=4)),
             measure(cnn.vgg11_thinned(num_classes=4))]

@@ -30,6 +30,8 @@ import resource
 import subprocess
 import sys
 
+from repro.launch.cache import enable_compile_cache
+
 COHORT = 32
 SHARD_SIZE = 16
 HOT_SHARDS = 8
@@ -94,6 +96,7 @@ def _run_child(population: int, rounds: int) -> dict:
 
 
 def main() -> None:
+    enable_compile_cache()
     ap = argparse.ArgumentParser()
     ap.add_argument("--smoke", action="store_true",
                     help="two populations, fewer rounds (CI)")

@@ -18,6 +18,7 @@ from repro.core import scaling as scaling_lib
 from repro.core.fsfl import run_federated
 from repro.core.protocol import ProtocolConfig, ServerState, make_protocol
 from repro.data import federated, synthetic
+from repro.launch.cache import enable_compile_cache
 from repro.models import cnn
 
 
@@ -105,6 +106,7 @@ def bidirectional_and_partial(rounds=6):
 
 
 def main():
+    enable_compile_cache()
     print("# Fig.3 analogue: scaling-factor statistics by depth (final round)")
     rows = fig3_scale_statistics()
     cols = list(rows[0].keys())

@@ -33,10 +33,12 @@ from repro.core.protocol import ProtocolConfig
 from repro.data import federated, synthetic
 from repro.fl import (TRAFFIC_PRESETS, get_scenario, list_scenarios,
                       run_scenario)
+from repro.launch.cache import enable_compile_cache
 from repro.models import cnn
 
 
 def main():
+    enable_compile_cache()
     ap = argparse.ArgumentParser()
     ap.add_argument("--rounds", type=int, default=None,
                     help="default: 10, or the scenario's registered rounds")

@@ -39,7 +39,10 @@ def run_engine(executor: str, rounds: int):
     from repro.data import federated, synthetic
     from repro.fl import EngineConfig, SamplingConfig, run_simulation
     from repro.fl.server_opt import ServerOptConfig
+    from repro.launch.cache import enable_compile_cache
     from repro.models import cnn
+
+    enable_compile_cache()
 
     task = synthetic.ImageTask("multipod", num_classes=4, channels=3,
                                size=32, prototypes_per_class=2, noise=0.25)
@@ -83,7 +86,9 @@ def parent_main(rounds: int, procs: int) -> int:
     print(f"== reference: 1 process, {procs} simulated devices, "
           "sharded backend ==")
     env = {k: v for k, v in os.environ.items() if k not in REPRO_ENV}
-    env.update(XLA_FLAGS=f"--xla_force_host_platform_device_count={procs}",
+    # the gloo demo is CPU-only: children never reach for an accelerator
+    env.update(JAX_PLATFORMS="cpu",
+               XLA_FLAGS=f"--xla_force_host_platform_device_count={procs}",
                PYTHONPATH=os.pathsep.join(
                    p for p in (os.environ.get("PYTHONPATH"), "src") if p))
     ref = subprocess.run(

@@ -11,10 +11,12 @@ import jax
 from repro.core.fsfl import run_federated
 from repro.core.protocol import ProtocolConfig
 from repro.data import federated, synthetic
+from repro.launch.cache import enable_compile_cache
 from repro.models import cnn
 
 
 def main():
+    enable_compile_cache()
     task = synthetic.ImageTask("quick", 10, 3, prototypes_per_class=2, noise=0.3)
     x, y = synthetic.make_image_dataset(jax.random.PRNGKey(0), task, 640)
     splits = federated.split_federated(jax.random.PRNGKey(1), x, y, num_clients=2)

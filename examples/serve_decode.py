@@ -17,10 +17,12 @@ import numpy as np
 
 from repro import comms
 from repro.fl.ingest import IngestConfig
+from repro.launch.cache import enable_compile_cache
 from repro.launch.ingest_serve import serve_cohort, synthetic_cohort
 
 
 def main():
+    enable_compile_cache()
     ap = argparse.ArgumentParser()
     ap.add_argument("--k", type=int, default=16, help="cohort size")
     ap.add_argument("--chunk", type=int, default=8)

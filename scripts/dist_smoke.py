@@ -6,9 +6,11 @@ topology the distributed job gets), then relaunches itself as 2 coordinated
 ``jax.distributed`` worker processes running ``dist_cohort_full`` on a
 localhost coordination service, and asserts record equality bit-for-bit.
 
-Sandboxes that forbid the coordination-service socket (bind failure,
-connection/deadline errors, or a coordination hang) print ``SKIPPED: ...``
-and exit 0 — the smoke must never fail CI for environment reasons.
+The demo is CPU-only (gloo collectives): every child runs with
+``JAX_PLATFORMS=cpu``, so none reaches for an accelerator.  Sandboxes that
+forbid the coordination-service socket (bind failure, or connection /
+deadline errors in a worker) print ``SKIPPED: ...`` and exit 0; workers
+that do not finish within ``TIMEOUT_S`` fail the smoke.
 
     PYTHONPATH=src python scripts/dist_smoke.py
 """
@@ -57,8 +59,10 @@ def _records_line(stdout: str):
 
 
 def parent_main() -> int:
+    # the gloo demo is CPU-only: children never reach for an accelerator
     base = {k: v for k, v in os.environ.items()
             if not k.startswith("REPRO_DIST_")}
+    base["JAX_PLATFORMS"] = "cpu"
     base["PYTHONPATH"] = os.pathsep.join(
         p for p in (os.environ.get("PYTHONPATH"), "src") if p)
     repo = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
@@ -108,9 +112,12 @@ def parent_main() -> int:
             out, err = p.communicate()
         outs.append((p.returncode, out, err))
 
+    if timed_out:
+        print(f"dist smoke FAILED: workers did not finish in {TIMEOUT_S} s")
+        return 1
     for pid, (rc, out, err) in enumerate(outs):
-        if rc != 0 or timed_out:
-            if timed_out or any(pat in err for pat in _SKIP_PATTERNS):
+        if rc != 0:
+            if any(pat in err for pat in _SKIP_PATTERNS):
                 print("SKIPPED: coordination service unavailable in this "
                       f"sandbox ({err[-300:]!r})")
                 return 0

@@ -104,28 +104,26 @@ class Int8BlockScaleCodec(Codec):
 
     Reuses the fused Pallas sparsify+quantize kernel from
     ``kernels/delta_compress.py`` (threshold 0: sparsification already
-    happened in the graph stages); on non-TPU backends the kernel runs in
-    interpret mode.  The scales section stays raw float32 — scale deltas are
-    ~1e-6 magnitude and precision-critical.  Worst-case reconstruction error
-    per block is ``amax/254`` (half a quantization step).
+    happened in the graph stages); off a TPU the kernel runs in the Pallas
+    interpreter (``repro.kernels.platform``).  The scales section stays raw
+    float32 — scale deltas are ~1e-6 magnitude and precision-critical.
+    Worst-case reconstruction error per block is ``amax/254`` (half a
+    quantization step).
     """
 
     name = "int8-blockscale"
     lossless = False
-    # encode dispatches the Pallas kernel through jax, but only ONE dispatch
-    # per message and the uplink's process pool is forkserver-based: each
-    # worker owns a fresh XLA runtime instead of inheriting forked thread
-    # state, so the process executor is safe for this codec
+    # encode dispatches the Pallas kernel through jax, ONE dispatch per
+    # message.  The uplink's process pool is forkserver-based and pins each
+    # worker's JAX to the CPU before any backend starts (the accelerator
+    # belongs to the parent), so a worker's encode is host work: its own
+    # CPU runtime, the kernel interpreted, bytes identical
     fork_safe = True
     block = 128
 
     def _kernel(self):
-        import jax
-
         from repro.kernels.delta_compress import delta_compress
-        interpret = jax.default_backend() != "tpu"
-        return lambda flat: delta_compress(flat, 0.0, block=self.block,
-                                           interpret=interpret)
+        return lambda flat: delta_compress(flat, 0.0, block=self.block)
 
     def _encode_body(self, upd: ClientUpdate, spec: WireSpec) -> bytes:
         # The per-leaf zero pad is wire LAYOUT, not a kernel requirement

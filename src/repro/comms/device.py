@@ -30,12 +30,14 @@ Every payload is byte-identical to the host ``encode_batch`` (asserted
 across codec × schema in tests/test_comms.py, and the frozen seed pins hold
 with ``device_encode=on`` in tests/test_rounds.py).  A function returns
 ``None`` when a device invariant fails (e.g. the zigzag range guard); the
-uplink then falls back to the host path for that cohort.
+uplink then hands that cohort to the host path.
 
-``dispatch_count()`` is a monotone counter of fused device programs
-launched here; ``fl/rounds.Uplink`` differences it around each cohort into
-the ``uplink.kernel_dispatches`` metric — the K×leaves → 1 collapse is the
-point, so it is observable.
+Three monotone counters make the path observable: ``dispatch_count()``
+(fused device programs launched here; ``fl/rounds.Uplink`` differences it
+around each cohort into the ``uplink.kernel_dispatches`` metric),
+``interpreted_count()`` (those of them that ran a Pallas kernel in the
+interpreter — 0 on a TPU) and ``handover_count()`` (cohorts handed back to
+the host path).
 
 This module is imported lazily (from the codecs' ``encode_cohort``
 overrides), so jax loads only when the device path is actually taken.
@@ -54,11 +56,14 @@ from repro.coding import nnc
 from repro.coding.bitstream import BitWriter
 from repro.comms.codec import (ClientUpdate, WireSpec, _cohort_size,
                                check_batch_clients, sorted_items)
+from repro.kernels import platform
 from repro.kernels.delta_compress import delta_compress_batch
 
 _ZIGZAG_SAFE = 2 ** 30   # |level| bound for exact int32 zigzag
 
 _dispatches = 0
+_interpreted = 0
+_handovers = 0
 
 
 def dispatch_count() -> int:
@@ -66,13 +71,27 @@ def dispatch_count() -> int:
     return _dispatches
 
 
-def _dispatched(n: int = 1) -> None:
-    global _dispatches
-    _dispatches += n
+def interpreted_count() -> int:
+    """Dispatches whose Pallas kernel ran in the interpreter (monotone)."""
+    return _interpreted
 
 
-def _interpret() -> bool:
-    return jax.default_backend() != "tpu"
+def handover_count() -> int:
+    """Cohorts the device encoders handed back to the host path (monotone)."""
+    return _handovers
+
+
+def _dispatched(interpreted: bool = False) -> None:
+    global _dispatches, _interpreted
+    _dispatches += 1
+    _interpreted += int(interpreted)
+
+
+def count_handover() -> None:
+    """Record one cohort handed to the host path (``fl/rounds.Uplink``
+    calls this whenever ``Codec.encode_cohort`` returns ``None``)."""
+    global _handovers
+    _handovers += 1
 
 
 def _ordered_stacked(tree: Any, allowed=None) -> list[tuple[str, Any]]:
@@ -133,9 +152,10 @@ def int8_encode_cohort(codec, out: Any, spec: WireSpec, *,
     padded = [n + (-n) % block for n in sizes]
     nblks = [p // block for p in padded]
     if sum(padded):
+        interpret = platform.interpret()
         q, s = _int8_program(tuple(l for _, l in p_items), block=block,
-                             interpret=_interpret())
-        _dispatched()
+                             interpret=interpret)
+        _dispatched(interpret)
     else:
         q = np.zeros((k, 0), np.int8)
         s = np.zeros((k, 0), np.float32)

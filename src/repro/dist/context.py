@@ -159,10 +159,7 @@ def _initialize_once(cfg: DistConfig) -> None:
     # computations aren't implemented on the CPU backend"); jax ships a gloo
     # TCP implementation behind this flag.  Must be set before the backend
     # initializes — harmless for GPU/TPU jobs, which ignore it.
-    try:
-        jax.config.update("jax_cpu_collectives_implementation", "gloo")
-    except Exception:  # noqa: BLE001 - older jaxlib without gloo; leave as-is
-        pass
+    jax.config.update("jax_cpu_collectives_implementation", "gloo")
     try:
         jax.distributed.initialize(
             coordinator_address=cfg.coordinator,

@@ -387,6 +387,10 @@ _POOL_SPEC: comms.WireSpec | None = None
 
 
 def _pool_init(codec: comms.Codec, spec: comms.WireSpec) -> None:
+    # The accelerator belongs to the parent (one process per chip): pin
+    # this worker's JAX to the CPU before any backend starts, so the
+    # codecs that dispatch through jax (int8-blockscale) encode on the host
+    jax.config.update("jax_platforms", "cpu")
     global _POOL_CODEC, _POOL_SPEC
     _POOL_CODEC, _POOL_SPEC = codec, spec
 
@@ -632,6 +636,8 @@ class Uplink:
                             codec=self.codec.name):
             payloads = self.codec.encode_cohort(out, self.spec,
                                                 clients=clients)
+        if payloads is None:
+            comms_device.count_handover()
         m = obs_metrics.get_registry()
         if m.enabled:
             m.count("uplink.kernel_dispatches",

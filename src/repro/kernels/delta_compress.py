@@ -1,25 +1,29 @@
-"""Pallas TPU kernel: fused differential-update compression (paper §3 on the
-mesh wire format) — threshold sparsify (Eq. 2 style) + per-block symmetric
-int8 quantization in ONE pass over the delta.
+"""Pallas TPU kernel: fused differential-update compression (paper §3 wire
+format) — threshold sparsify (Eq. 2 style) + per-block symmetric int8
+quantization in ONE pass over the delta.
 
 The unfused jnp pipeline reads the delta three times (mask, max, quantize);
-this kernel streams each 1-D block through VMEM once and emits the int8
-payload + per-block scale, which is exactly what dist/collectives.py puts on
-the wire.  Memory-bound: one HBM read, 1/4 + eps write.
+this kernel streams the delta through VMEM once and emits the int8 payload
++ per-block scale.  Memory-bound: one HBM read, 1/4 + eps write.
 
-Two dispatch shapes:
+Layout.  A wire block of ``block`` consecutive elements is one ROW of a
+2-D ``(nblk, block)`` view, so the per-block amax is a lane reduction and
+every Mosaic tile is ``(rows, block)``: ``rows`` is the whole row count or
+a multiple of 32 (the int8 sublane tiling), ``block`` the full last dim.
+Each grid program takes ``_TILE_BYTES`` of f32 input whatever the model
+width; a partial last tile is clipped by Pallas (rows are independent, so
+its out-of-range rows are never written back).  Scalars (theta, coef)
+ride in SMEM.
 
-  * ``delta_compress`` — the per-client ``(n,)`` variant; one grid program
-    per ``block`` elements.  Ragged ``n`` is handled INSIDE the jitted
-    wrapper (device-side zero pad + slice), so callers never ``np.pad``.
-    Zero padding cannot move a byte: padded lanes quantize to 0 and an
-    all-pad block gets the same scale-1 sentinel the host layout pins.
+  * ``delta_compress`` — the per-client ``(n,)`` variant.  Ragged ``n``
+    is zero-padded INSIDE the jitted wrapper to a block multiple; padded
+    lanes quantize to 0 and an all-pad block gets the scale-1 sentinel
+    the host layout pins.
   * ``delta_compress_batch`` — the cohort variant over stacked ``(K, n)``
-    deltas: as many client rows per grid program as a VMEM budget allows
-    (small cohorts collapse to ONE program), each program reshaping its
-    rows to ``(-1, block)`` so the per-128-block wire scales are
-    bit-identical to ``K`` separate calls while grid iteration drops from
-    O(K · n/block) to O(K·n / budget).  This is the uplink's device fast
+    deltas: rows are padded to a block multiple and the whole cohort is
+    ONE ``(K·nblk, block)`` view, so row ``i`` of the output is
+    bit-identical to ``delta_compress(deltas[i])`` — each block is reduced
+    alone, whichever program holds it.  This is the uplink's device fast
     path (``repro.comms.device``).
 
 Companion: `delta_apply` — fused dequant + server-side apply (W += c·q·s).
@@ -31,154 +35,142 @@ import functools
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
+
+from repro.kernels import platform
+
+# f32 input bytes per grid program: double-buffered input and output plus
+# the in-kernel temporaries stay well under a v5e core's 16 MiB scoped VMEM
+_TILE_BYTES = 1 << 20
+# int8 arrays tile as (32, 128) on TPU: a partial-array row block must be a
+# multiple of this
+_INT8_ROWS = 32
+
+_SMEM = pl.BlockSpec(memory_space=pltpu.SMEM)
 
 
-def _compress_kernel(d_ref, theta_ref, q_ref, s_ref):
-    d = d_ref[...].astype(jnp.float32)
-    theta = theta_ref[0]
-    kept = jnp.where(jnp.abs(d) >= theta, d, 0.0)
-    amax = jnp.max(jnp.abs(kept))
+def _tile_rows(nrows: int, width: int) -> int:
+    """Rows per program: all of them if they fit, else the largest
+    multiple of 32 under the byte budget."""
+    cap = max(_INT8_ROWS,
+              _TILE_BYTES // (width * 4) // _INT8_ROWS * _INT8_ROWS)
+    return nrows if nrows <= cap else cap
+
+
+def _scalar(x) -> jax.Array:
+    return jnp.reshape(jnp.asarray(x, jnp.float32), (1,))
+
+
+def _compress_kernel(theta_ref, d_ref, q_ref, s_ref):
+    d = d_ref[...].astype(jnp.float32)                  # (rows, block)
+    kept = jnp.where(jnp.abs(d) >= theta_ref[0], d, 0.0)
+    amax = jnp.max(jnp.abs(kept), axis=1, keepdims=True)
     scale = jnp.where(amax > 0, amax / 127.0, 1.0)
     q_ref[...] = jnp.clip(jnp.round(kept / scale), -127, 127).astype(jnp.int8)
-    s_ref[0] = scale
+    s_ref[...] = scale
+
+
+def _compress_blocks(blocks: jax.Array, theta, interpret: bool):
+    """(nblk, block) -> (q int8 (nblk, block), scales f32 (nblk,))."""
+    nblk, block = blocks.shape
+    rows = _tile_rows(nblk, block)
+    q, scales = pl.pallas_call(
+        _compress_kernel,
+        grid=(pl.cdiv(nblk, rows),),
+        in_specs=[_SMEM, pl.BlockSpec((rows, block), lambda i: (i, 0))],
+        out_specs=[pl.BlockSpec((rows, block), lambda i: (i, 0)),
+                   pl.BlockSpec((rows, 1), lambda i: (i, 0))],
+        out_shape=[jax.ShapeDtypeStruct((nblk, block), jnp.int8),
+                   jax.ShapeDtypeStruct((nblk, 1), jnp.float32)],
+        interpret=interpret,
+    )(_scalar(theta), blocks)
+    return q, scales[:, 0]
 
 
 @functools.partial(jax.jit, static_argnames=("block", "interpret"))
-def delta_compress(delta: jax.Array, theta: jax.Array, *, block: int = 1024,
-                   interpret: bool = False):
+def _delta_compress(delta, theta, *, block: int, interpret: bool):
+    n = delta.shape[0]
+    if n == 0:
+        return (jnp.zeros((0,), jnp.int8), jnp.zeros((0,), jnp.float32))
+    flat = jnp.pad(delta, (0, (-n) % block))
+    q, scales = _compress_blocks(flat.reshape(-1, block), theta, interpret)
+    return q.reshape(-1)[:n], scales
+
+
+def delta_compress(delta: jax.Array, theta, *, block: int = 1024,
+                   interpret: bool | None = None):
     """delta: (n,) for ANY n (padded device-side); theta: scalar (Eq. 2).
 
     Returns (q int8 (n,), scales f32 (ceil(n/block),)).  The scale of a
     trailing partial block is computed over the zero-padded block — zeros
     never win the amax, so it equals the unpadded block's scale.
+    ``interpret=None`` takes ``platform.interpret()``.
     """
-    n = delta.shape[0]
-    if n == 0:
-        return (jnp.zeros((0,), jnp.int8), jnp.zeros((0,), jnp.float32))
-    pad = (-n) % block
-    flat = jnp.pad(delta, (0, pad)) if pad else delta
-    nblk = flat.shape[0] // block
-    theta_arr = jnp.broadcast_to(jnp.asarray(theta, jnp.float32), (1,))
-    q, scales = pl.pallas_call(
-        _compress_kernel,
-        grid=(nblk,),
-        in_specs=[
-            pl.BlockSpec((block,), lambda i: (i,)),
-            pl.BlockSpec((1,), lambda i: (0,)),
-        ],
-        out_specs=[
-            pl.BlockSpec((block,), lambda i: (i,)),
-            pl.BlockSpec((1,), lambda i: (i,)),
-        ],
-        out_shape=[jax.ShapeDtypeStruct((flat.shape[0],), jnp.int8),
-                   jax.ShapeDtypeStruct((nblk,), jnp.float32)],
-        interpret=interpret,
-    )(flat, theta_arr)
-    return (q[:n] if pad else q), scales
-
-
-# per-program f32 input budget for the batch kernel: rows are grouped so
-# one program's working set stays well under a TPU core's ~16 MB VMEM
-# (input + kept + quantized copies ~3x this)
-_VMEM_ROW_BYTES = 2 << 20
-
-
-def _compress_row_kernel(d_ref, theta_ref, q_ref, s_ref, *, block):
-    # One program per GROUP of client rows; the reshape keeps per-`block`
-    # scales bit-identical to the per-block grid above — each length-block
-    # slice of a row is reduced independently, however many rows ride in
-    # one program.
-    d = d_ref[...].astype(jnp.float32).reshape(-1, block)
-    theta = theta_ref[0]
-    kept = jnp.where(jnp.abs(d) >= theta, d, 0.0)
-    amax = jnp.max(jnp.abs(kept), axis=1)
-    scale = jnp.where(amax > 0, amax / 127.0, 1.0)
-    q = jnp.clip(jnp.round(kept / scale[:, None]), -127, 127)
-    q_ref[...] = q.astype(jnp.int8).reshape(q_ref.shape)
-    s_ref[...] = scale.reshape(s_ref.shape)
+    return _delta_compress(delta, theta, block=block,
+                           interpret=platform.resolve(interpret))
 
 
 @functools.partial(jax.jit, static_argnames=("block", "interpret"))
-def delta_compress_batch(deltas: jax.Array, theta: jax.Array, *,
-                         block: int = 128, interpret: bool = False):
+def _delta_compress_batch(deltas, theta, *, block: int, interpret: bool):
+    k, n = deltas.shape
+    if n == 0 or k == 0:
+        return (jnp.zeros((k, 0), jnp.int8), jnp.zeros((k, 0), jnp.float32))
+    flat = jnp.pad(deltas, ((0, 0), (0, (-n) % block)))
+    p = flat.shape[1]
+    q, scales = _compress_blocks(flat.reshape(-1, block), theta, interpret)
+    return q.reshape(k, p)[:, :n], scales.reshape(k, p // block)
+
+
+def delta_compress_batch(deltas: jax.Array, theta, *, block: int = 128,
+                         interpret: bool | None = None):
     """Cohort variant: deltas (K, n) for ANY n, ONE pallas dispatch.
 
     Returns (q int8 (K, n), scales f32 (K, ceil(n/block))), row i byte-equal
     to ``delta_compress(deltas[i], theta, block=block)``.
     """
-    k, n = deltas.shape
-    if n == 0 or k == 0:
-        return (jnp.zeros((k, 0), jnp.int8), jnp.zeros((k, 0), jnp.float32))
-    pad = (-n) % block
-    flat = jnp.pad(deltas, ((0, 0), (0, pad))) if pad else deltas
-    p = flat.shape[1]
-    nblk = p // block
-    # group rows per program under the VMEM budget: a tiny cohort runs in
-    # ONE program, a huge model still tiles row-by-row.  Zero-padded rows
-    # quantize to (q=0, scale=1) and are sliced away.
-    rows = min(k, max(1, _VMEM_ROW_BYTES // (p * 4)))
-    kpad = (-k) % rows
-    if kpad:
-        flat = jnp.pad(flat, ((0, kpad), (0, 0)))
-    kp = k + kpad
-    theta_arr = jnp.broadcast_to(jnp.asarray(theta, jnp.float32), (1,))
-    q, scales = pl.pallas_call(
-        functools.partial(_compress_row_kernel, block=block),
-        grid=(kp // rows,),
-        in_specs=[
-            pl.BlockSpec((rows, p), lambda i: (i, 0)),
-            pl.BlockSpec((1,), lambda i: (0,)),
-        ],
-        out_specs=[
-            pl.BlockSpec((rows, p), lambda i: (i, 0)),
-            pl.BlockSpec((rows, nblk), lambda i: (i, 0)),
-        ],
-        out_shape=[jax.ShapeDtypeStruct((kp, p), jnp.int8),
-                   jax.ShapeDtypeStruct((kp, nblk), jnp.float32)],
-        interpret=interpret,
-    )(flat, theta_arr)
-    return q[:k, :n], scales[:k]
+    return _delta_compress_batch(deltas, theta, block=block,
+                                 interpret=platform.resolve(interpret))
 
 
-def _apply_kernel(w_ref, q_ref, s_ref, coef_ref, o_ref):
-    deq = q_ref[...].astype(jnp.float32) * s_ref[0]
+def _apply_kernel(coef_ref, w_ref, q_ref, s_ref, o_ref):
+    deq = q_ref[...].astype(jnp.float32) * s_ref[...]
     o_ref[...] = (w_ref[...].astype(jnp.float32)
                   + coef_ref[0] * deq).astype(o_ref.dtype)
 
 
 @functools.partial(jax.jit, static_argnames=("block", "interpret"))
+def _delta_apply(w, q, scales, coef, *, block: int, interpret: bool):
+    n = w.shape[0]
+    if q.shape != (n,):
+        raise ValueError(f"q has shape {q.shape}, want ({n},)")
+    if n == 0:
+        return w
+    pad = (-n) % block
+    nblk = (n + pad) // block
+    if scales.shape != (nblk,):
+        raise ValueError(f"scales has shape {scales.shape}, want ({nblk},)")
+    rows = _tile_rows(nblk, block)
+    tile = pl.BlockSpec((rows, block), lambda i: (i, 0))
+    out = pl.pallas_call(
+        _apply_kernel,
+        grid=(pl.cdiv(nblk, rows),),
+        in_specs=[_SMEM, tile, tile,
+                  pl.BlockSpec((rows, 1), lambda i: (i, 0))],
+        out_specs=tile,
+        out_shape=jax.ShapeDtypeStruct((nblk, block), w.dtype),
+        interpret=interpret,
+    )(_scalar(coef), jnp.pad(w, (0, pad)).reshape(nblk, block),
+      jnp.pad(q, (0, pad)).reshape(nblk, block), scales.reshape(nblk, 1))
+    return out.reshape(-1)[:n]
+
+
 def delta_apply(w: jax.Array, q: jax.Array, scales: jax.Array,
-                coef: float = 1.0, *, block: int = 1024,
-                interpret: bool = False) -> jax.Array:
+                coef=1.0, *, block: int = 1024,
+                interpret: bool | None = None) -> jax.Array:
     """Fused dequantize + apply: returns w + coef * (q * scale).
 
     Accepts ANY n (padded device-side); scales has ceil(n/block) entries —
     the layout ``delta_compress`` emits.
     """
-    n = w.shape[0]
-    assert q.shape == (n,)
-    if n == 0:
-        return w
-    pad = (-n) % block
-    if pad:
-        w_p = jnp.pad(w, (0, pad))
-        q_p = jnp.pad(q, (0, pad))
-    else:
-        w_p, q_p = w, q
-    nblk = w_p.shape[0] // block
-    assert scales.shape == (nblk,), (scales.shape, nblk)
-    coef_arr = jnp.broadcast_to(jnp.asarray(coef, jnp.float32), (1,))
-    out = pl.pallas_call(
-        _apply_kernel,
-        grid=(nblk,),
-        in_specs=[
-            pl.BlockSpec((block,), lambda i: (i,)),
-            pl.BlockSpec((block,), lambda i: (i,)),
-            pl.BlockSpec((1,), lambda i: (i,)),
-            pl.BlockSpec((1,), lambda i: (0,)),
-        ],
-        out_specs=pl.BlockSpec((block,), lambda i: (i,)),
-        out_shape=jax.ShapeDtypeStruct((w_p.shape[0],), w.dtype),
-        interpret=interpret,
-    )(w_p, q_p, scales, coef_arr)
-    return out[:n] if pad else out
+    return _delta_apply(w, q, scales, coef, block=block,
+                        interpret=platform.resolve(interpret))

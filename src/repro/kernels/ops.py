@@ -1,20 +1,17 @@
-"""Jit'd public wrappers for the Pallas kernels: shape padding + dispatch.
+"""Public wrappers for the 2-D Pallas kernels: shape padding + dispatch.
 
-On CPU (this container) the kernels run with interpret=True; on real TPU the
-same call sites compile to Mosaic.  `INTERPRET` flips automatically.
+Each call resolves interpret mode through ``repro.kernels.platform``:
+compiled by Mosaic where JAX's default backend is a TPU, interpreted
+elsewhere.  The wire kernels (``delta_compress``, ``level_assign``) pad
+ragged shapes themselves and are called directly.
 """
 from __future__ import annotations
 
-import functools
-
-import jax
 import jax.numpy as jnp
 
-from repro.kernels import delta_compress as dc
+from repro.kernels import platform
 from repro.kernels import row_stats as rs
 from repro.kernels import scaled_matmul as sm
-
-INTERPRET = jax.default_backend() != "tpu"
 
 
 def _pad_to(x, axis, mult):
@@ -35,36 +32,13 @@ def scaled_matmul(x, w, s, *, bm=128, bn=128, bk=128):
     w2, _ = _pad_to(w2, 1, bk)
     s2, _ = _pad_to(s, 0, bn)
     out = sm.scaled_matmul(x2, w2, s2, bm=bm, bn=bn, bk=bk,
-                           interpret=INTERPRET)
+                           interpret=platform.interpret())
     return out[:M, :N]
-
-
-def delta_compress(delta, theta, *, block=1024):
-    # ragged n pads device-side inside the jitted kernel wrapper
-    q, scales = dc.delta_compress(delta.reshape(-1), theta, block=block,
-                                  interpret=INTERPRET)
-    return q.reshape(delta.shape), scales
-
-
-def delta_compress_flat(delta, theta, *, block=1024):
-    """Flat (n,) variant for pre-padded buckets (the dist path)."""
-    return dc.delta_compress(delta, theta, block=block, interpret=INTERPRET)
-
-
-def delta_compress_batch(deltas, theta, *, block=128):
-    """Cohort (K, n) variant: one dispatch, rows byte-equal to per-client."""
-    return dc.delta_compress_batch(deltas, theta, block=block,
-                                   interpret=INTERPRET)
-
-
-def delta_apply(w, q, scales, coef=1.0, *, block=1024):
-    return dc.delta_apply(w, q, scales, coef, block=block,
-                          interpret=INTERPRET)
 
 
 def row_stats(w, *, bm=128, bn=512):
     w2, M = _pad_to(w, 0, bm)
     w2, N = _pad_to(w2, 1, bn)
-    out = rs.row_stats(w2, bm=bm, bn=bn, interpret=INTERPRET)
+    out = rs.row_stats(w2, bm=bm, bn=bn, interpret=platform.interpret())
     # padding zeros dilute the mean; rescale to the true column count
     return out[:M] * (w2.shape[1] / N)

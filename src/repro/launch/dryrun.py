@@ -1,12 +1,9 @@
-import os
-os.environ["XLA_FLAGS"] = (os.environ.get("REPRO_XLA_FLAGS")
-                           or "--xla_force_host_platform_device_count=512")
-
 """Multi-pod dry-run: lower + compile every (arch x input-shape) combination
 on the production meshes and record memory/cost/collective analysis.
 
-MUST be run as its own process (the XLA flag above is read at first jax
-init).  Usage:
+MUST be run as its own process: ``main`` sets ``XLA_FLAGS`` (512 simulated
+host devices, or ``REPRO_XLA_FLAGS``), which XLA reads at first backend
+init.  Usage:
 
     PYTHONPATH=src python -m repro.launch.dryrun --arch gemma2-9b \
         --shape train_4k [--multi-pod] [--out results.json]
@@ -16,6 +13,7 @@ Writes/updates a JSON results file (benchmarks/roofline reads it).
 """
 import argparse
 import json
+import os
 import re
 import sys
 import time
@@ -206,6 +204,8 @@ def default_microbatches(cfg) -> int:
 
 
 def main():
+    os.environ["XLA_FLAGS"] = (os.environ.get("REPRO_XLA_FLAGS")
+                               or "--xla_force_host_platform_device_count=512")
     from repro.launch import require_dist
     require_dist()
     ap = argparse.ArgumentParser()

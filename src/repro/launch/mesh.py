@@ -1,20 +1,31 @@
 """Production mesh construction (function, not module-level constant, so
-importing this module never touches jax device state)."""
+importing this module never touches jax device state).
+
+Every mesh here has ``AxisType.Auto`` axes: the engine slices and pads its
+sharded cohort outputs with plain indexing and leaves the partitioning to
+XLA, which ``jax.make_mesh``'s default ``Explicit`` axes refuse.
+"""
 from __future__ import annotations
 
 import jax
+from jax.sharding import AxisType
+
+
+def _auto_mesh(shape: tuple, axes: tuple):
+    return jax.make_mesh(tuple(shape), tuple(axes),
+                         axis_types=(AxisType.Auto,) * len(axes))
 
 
 def make_production_mesh(*, multi_pod: bool = False):
     """16x16 = 256 chips per pod; 2 pods = 512 chips multi-pod."""
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return jax.make_mesh(shape, axes)
+    return _auto_mesh(shape, axes)
 
 
 def make_mesh(shape: tuple, axes: tuple):
     """Arbitrary mesh (tests use small host-device meshes)."""
-    return jax.make_mesh(shape, axes)
+    return _auto_mesh(shape, axes)
 
 
 def make_cohort_mesh(mesh_shape: tuple[int, ...] | None = None,
@@ -31,7 +42,7 @@ def make_cohort_mesh(mesh_shape: tuple[int, ...] | None = None,
     if len(mesh_shape) != 1:
         raise ValueError(
             f"cohort mesh is 1-D (the client axis); got shape {mesh_shape!r}")
-    return jax.make_mesh(tuple(mesh_shape), (axis,))
+    return _auto_mesh(mesh_shape, (axis,))
 
 
 def make_multihost_cohort_mesh(axis: str = "clients"):

@@ -8,19 +8,18 @@ dry-run lowers at 512 chips).
     PYTHONPATH=src python -m repro.launch.train --arch mixtral-8x22b \
         --reduced --steps 10 --dense
 """
-import os
-
-if "XLA_FLAGS" not in os.environ:
-    os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=8"
-
 import argparse
 import dataclasses
+import os
 
 import jax
 import jax.numpy as jnp
 
 
 def main():
+    # 8 simulated host devices for the (4, 2) mesh; read at first backend init
+    os.environ.setdefault("XLA_FLAGS",
+                          "--xla_force_host_platform_device_count=8")
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", required=True)
     ap.add_argument("--steps", type=int, default=10)

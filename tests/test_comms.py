@@ -562,15 +562,11 @@ def test_every_repro_module_imports_without_mesh_runtime():
 
     import repro
 
-    saved = os.environ.get("XLA_FLAGS")  # launch modules set this at import
-    try:
-        for mod in pkgutil.walk_packages(repro.__path__, prefix="repro."):
-            importlib.import_module(mod.name)
-    finally:
-        if saved is None:
-            os.environ.pop("XLA_FLAGS", None)
-        else:
-            os.environ["XLA_FLAGS"] = saved
+    saved = os.environ.get("XLA_FLAGS")
+    for mod in pkgutil.walk_packages(repro.__path__, prefix="repro."):
+        importlib.import_module(mod.name)
+    assert os.environ.get("XLA_FLAGS") == saved, \
+        "importing a module must not set XLA_FLAGS"
 
     import repro.dist
     from repro.launch import require_dist

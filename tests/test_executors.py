@@ -302,6 +302,18 @@ def test_scenario_registration_validates_executor_axes():
 
 # ------------------------------------------------------------- real sharding
 
+def test_cohort_mesh_axes_are_auto():
+    """``jax.make_mesh`` defaults to Explicit axes, under which slicing the
+    sharded cohort output (the padded rows dropped) raises; every mesh the
+    engine builds must use Auto axes."""
+    from jax.sharding import AxisType
+
+    from repro.launch.mesh import make_cohort_mesh, make_mesh
+
+    for mesh in (make_cohort_mesh(), make_mesh((1, 1), ("data", "model"))):
+        assert set(mesh.axis_types) == {AxisType.Auto}
+
+
 _MULTIDEV_SCRIPT = r'''
 import jax, jax.numpy as jnp, numpy as np
 assert len(jax.devices()) == 2, jax.devices()

@@ -26,7 +26,7 @@ from repro.kernels.level_assign import level_assign
 
 # ------------------------------------------------- ragged delta_compress
 
-@pytest.mark.parametrize("n", [0, 5, 127, 128, 1000])
+@pytest.mark.parametrize("n", [0, 5, 127, 128, 1000, 300_001])
 def test_delta_compress_ragged_shapes(n):
     """Non-block-multiple n pads device-side INSIDE the jitted wrapper
     (the (n,) API stays; scales keep the ceil(n/block) layout)."""
@@ -40,9 +40,7 @@ def test_delta_compress_ragged_shapes(n):
                                rtol=1e-6)
 
 
-def test_delta_compress_ragged_roundtrips_through_apply():
-    """delta_apply accepts the same ragged n (pads, slices back)."""
-    n = 777
+def _roundtrip_through_apply(n):
     k = jax.random.PRNGKey(21)
     w = jax.random.normal(k, (n,))
     d = jax.random.normal(jax.random.fold_in(k, 1), (n,)) * 0.1
@@ -53,6 +51,17 @@ def test_delta_compress_ragged_roundtrips_through_apply():
     deq = (deq.reshape(-1, 128) * np.asarray(scales)[:, None]).reshape(-1)
     np.testing.assert_allclose(np.asarray(out), np.asarray(w) + deq[:n],
                                rtol=1e-6)
+
+
+def test_delta_compress_ragged_roundtrips_through_apply():
+    """delta_apply accepts the same ragged n (pads, slices back)."""
+    _roundtrip_through_apply(777)
+
+
+def test_delta_apply_spans_tiles():
+    """Past one grid program's tile (2048 blocks of 128): the partial
+    last tile is clipped, never written out of range."""
+    _roundtrip_through_apply(300_001)
 
 
 # ------------------------------------------------- delta_compress_batch
@@ -75,8 +84,9 @@ def test_delta_compress_batch_bitwise_vs_single(k):
 
 
 @pytest.mark.parametrize("k", [1, 4])
-@pytest.mark.parametrize("n", [128, 512])
+@pytest.mark.parametrize("n", [128, 512, 100_000])
 def test_delta_compress_batch_matches_ref(k, n):
+    # n=100_000, k=4: 3128 blocks span two tiles, the last one partial
     d = jax.random.normal(jax.random.PRNGKey(k * 7 + n), (k, n)) * 0.2
     qb, sb = delta_compress_batch(d, 0.1, block=128, interpret=True)
     q_ref, s_ref = ref.delta_compress_batch(d, 0.1, 128)
@@ -92,12 +102,13 @@ def test_delta_compress_batch_empty():
 
 # ------------------------------------------------- level_assign
 
-@pytest.mark.parametrize("k", [1, 4, 8])
-@pytest.mark.parametrize("n", [64, 257, 1024])
+@pytest.mark.parametrize("k", [1, 4, 8, 10])
+@pytest.mark.parametrize("n", [64, 257, 1024, 20_000])
 def test_level_assign_matches_ref(k, n):
     """Fused carry+sparsify+quantize vs the residual.py/quant.py chain:
     LEVELS (the wire data) are bitwise; the float carry may differ by FMA
-    contraction in `carried - lv * step`."""
+    contraction in `carried - lv * step`.  k=10 and n=20_000 leave
+    partial edge tiles on both grid axes (8 rows x 16384 columns)."""
     key = jax.random.PRNGKey(k * 31 + n)
     d = jax.random.normal(key, (k, n)) * 1e-2
     r = jax.random.normal(jax.random.fold_in(key, 1), (k, n)) * 1e-3
@@ -133,3 +144,26 @@ def test_level_assign_clips_to_max_level():
     r = jnp.zeros((1, 3))
     lv, _ = level_assign(d, r, 0.0, 1e-4, max_level=7, interpret=True)
     np.testing.assert_array_equal(np.asarray(lv), [[7, -7, 0]])
+
+
+# ------------------------------------------------- platform choice
+
+def test_interpret_follows_the_platform():
+    """One place decides interpret mode: off a TPU every kernel whose
+    ``interpret`` is left as None runs in the interpreter, an explicit
+    flag wins, and a run that asks for the chip fails instead of
+    falling back."""
+    from repro.kernels import platform
+
+    on_tpu = jax.default_backend() == "tpu"
+    assert platform.interpret() is (not on_tpu)
+    assert platform.resolve(None) is platform.interpret()
+    assert platform.resolve(True) is True and platform.resolve(False) is False
+    if not on_tpu:
+        with pytest.raises(RuntimeError, match="no TPU"):
+            platform.require_tpu()
+    d = jax.random.normal(jax.random.PRNGKey(3), (2, 300)) * 0.3
+    q, s = delta_compress_batch(d, 0.05, block=128)
+    qi, si = delta_compress_batch(d, 0.05, block=128,
+                                  interpret=platform.interpret())
+    np.testing.assert_array_equal(np.asarray(q), np.asarray(qi))

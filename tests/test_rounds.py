@@ -754,3 +754,21 @@ def test_sync_scheduler_empty_cohort_is_all_drop_round(tiny2):
     assert first.down_bytes == 0  # no server step happened
     assert second.participants != () and second.up_bytes > 0
     assert second.sim_time_s >= first.sim_time_s > 0.0  # clock advanced
+
+
+def test_device_encode_counts_host_handovers(tiny2):
+    """Every cohort the device path hands back to the host encoder is
+    counted (a chip run asserts 0); a codec with a fast path hands none
+    over, and off a TPU its kernel dispatch is counted as interpreted."""
+    from repro.comms import device as comms_device
+
+    model, splits = tiny2
+    cfg = ProtocolConfig(name="fedavg", method="none", quantize=False,
+                         batch_size=32, local_lr=2e-3)
+    for codec, handed in (("raw-fp32", 1), ("int8-blockscale", 0)):
+        before = (comms_device.handover_count(),
+                  comms_device.interpreted_count())
+        run_simulation(model, cfg, splits, 1, jax.random.PRNGKey(7),
+                       engine=EngineConfig(device_encode=True, codec=codec))
+        assert comms_device.handover_count() - before[0] == handed
+        assert comms_device.interpreted_count() - before[1] == 1 - handed

@@ -19,8 +19,16 @@ synthetic data made from ``--seed``, and checks the results:
                   relative L2 of the reconstructed update
                   <= CLIENT_RTOL_DEFAULT at the chip's default matmul
                   precision and <= CLIENT_RTOL_HIGHEST at "highest"
+  fsfl_round      the engine's fsfl client round (top-10% selection,
+                  quantization, filter-scale sub-epochs, accept-if-improves)
+                  on the chip against the host CPU at "highest", made
+                  comparable as ``fsfl_round`` says: the share of the
+                  reference's nonzero parameter levels that differ
+                  <= FSFL_LEVELS_TOL, and the relative L2 of the
+                  reconstructed filter-scale update <= FSFL_SCALES_TOL
   engine          the default engine path: vmap executor, the "auto" codec
-                  (nnc-cabac, host CABAC), sync FedAvg, full participation
+                  (nnc-cabac, host CABAC), sync FedAvg, CLIENTS clients in
+                  full participation, ROUNDS rounds
   device_encode   the same run with the int8-blockscale codec encoded on
                   the device (``EngineConfig.device_encode``), the path on
                   which the Pallas cohort kernel runs
@@ -39,7 +47,7 @@ Without a TPU (or outside a checkout of this repository) the script exits
 non-zero and prints no result.  The last line of a passing run is
 ``{"ok": true, "device": {"platform": ..., "kind": ..., "count": ...}}``.
 
-    python chip_smoke.py [--seed 0] [--rounds 3] [--clients 10]
+    python chip_smoke.py [--seed 0]
     python chip_smoke.py --chips 4
 """
 from __future__ import annotations
@@ -61,6 +69,23 @@ SRC = Path(__file__).resolve().parent / "src"
 # of 1e-6 (float32 rounding) with ~1e-3.
 CLIENT_RTOL_DEFAULT = 0.6
 CLIENT_RTOL_HIGHEST = 1e-2
+
+# Bounds of the fsfl check (``fsfl_round``) at "highest": the share of the
+# reference's nonzero parameter levels that differ, and the relative L2 of
+# the reconstructed filter-scale update.  On the CPU a relative nudge of
+# the weights of 1e-6 reads 0.0037 / 9.5e-4 and one of 1e-4 reads
+# 0.060 / 4.2e-3; one of 4e-3 (the order of a bf16 pass) reads
+# 0.455 / 0.020, a top-20% selection for top-10% 0.36 / 0.019, twice the
+# quantization step 0.88 / 0.017, a scale learning rate 1% off 0 / 9.3e-3
+# and no scale training 0 / 1.0.
+FSFL_LEVELS_TOL = 0.05
+FSFL_SCALES_TOL = 5e-3
+
+# The run: the synthetic CIFAR-like images are split 70/15/15 into client
+# train / client val / test across CLIENTS clients.
+ROUNDS = 3
+CLIENTS = 10
+SAMPLES = 6400
 
 BACKEND_COMPILE_EVENT = "/jax/core/compile/backend_compile_duration"
 
@@ -201,6 +226,16 @@ def phase_kernels(n: int, k: int, seed: int) -> None:
          int8_codes_differing_from_host_cpu_oracle=int((q != q_cpu).sum()))
 
 
+def one_step_round(model, splits, scenario, rounds: int, k: int, seed: int,
+                   **changes):
+    """``cohort_inputs`` for ``scenario`` with ``changes`` made to it, cut
+    to one local step."""
+    scenario = dataclasses.replace(scenario, **changes)
+    client_round, args = cohort_inputs(model, splits, scenario, rounds, k,
+                                       seed)
+    return client_round, args[:-1] + (args[-1][:, :1],)
+
+
 def conditioned_round(model, splits, scenario, rounds: int, k: int,
                       seed: int):
     """The client round the reference checks compare, and its inputs.
@@ -214,11 +249,23 @@ def conditioned_round(model, splits, scenario, rounds: int, k: int,
     comparison is conditioned: the dense protocol (no sparsify / quantize
     stages) and one local SGD step.
     """
-    cond = dataclasses.replace(scenario, protocol="fedavg",
-                               protocol_overrides=(("local_optimizer",
-                                                    "sgd"),))
-    client_round, args = cohort_inputs(model, splits, cond, rounds, k, seed)
-    return client_round, args[:-1] + (args[-1][:, :1],)   # one local step
+    return one_step_round(model, splits, scenario, rounds, k, seed,
+                          protocol="fedavg",
+                          protocol_overrides=(("local_optimizer", "sgd"),))
+
+
+def fsfl_round(model, splits, scenario, rounds: int, k: int, seed: int):
+    """The engine's fsfl client round, every stage of it (error feedback,
+    top-10% selection, quantization, filter-scale sub-epochs with
+    accept-if-improves, fine quantization of the scales), with both
+    optimizers SGD and one local step.  A first Adam step moves every
+    weight by about its learning rate whatever the gradient, so under Adam
+    which weights the top-10% keeps is decided by float32 rounding
+    (``conditioned_round``); under SGD the kept set follows the gradient.
+    """
+    return one_step_round(model, splits, scenario, rounds, k, seed,
+                          protocol_overrides=(("local_optimizer", "sgd"),
+                                              ("scale_optimizer", "sgd")))
 
 
 def compare(name: str, run_test, run_ref, **fields) -> None:
@@ -252,6 +299,38 @@ def phase_client_round(model, splits, scenario, rounds: int, k: int,
             lambda: vround(*host_args), cohort=k, reference="cpu")
 
 
+def phase_fsfl_round(model, splits, scenario, rounds: int, k: int,
+                     seed: int) -> None:
+    import jax
+    import numpy as np
+
+    client_round, args = fsfl_round(model, splits, scenario, rounds, k, seed)
+    vround = jax.jit(jax.vmap(client_round, in_axes=(None, 0, 0, 0, 0, 0, 0)))
+    host_args = jax.device_put(args, jax.devices("cpu")[0])
+    with jax.default_matmul_precision("highest"):
+        got, want = jax.device_get((vround(*args), vround(*host_args)))
+    lv = np.concatenate([x.ravel() for x in jax.tree.leaves(got.levels_params)])
+    lv_ref = np.concatenate([x.ravel()
+                             for x in jax.tree.leaves(want.levels_params)])
+    kept = int((lv_ref != 0).sum())
+    levels_off = float((lv != lv_ref).sum() / max(kept, 1))
+    scales_err = rel_l2(got.recon_delta_scales, want.recon_delta_scales)
+    scales_moved = any(np.any(x != 0)
+                       for x in jax.tree.leaves(want.recon_delta_scales))
+    emit("fsfl_round", cohort=k, reference="cpu", precision="highest",
+         nonzero_levels=kept, levels_differing_share=levels_off,
+         scales_rel_l2=scales_err, tol_levels=FSFL_LEVELS_TOL,
+         tol_scales=FSFL_SCALES_TOL)
+    check(kept > 0 and scales_moved,
+          "fsfl_round: the CPU reference sent no levels or no scale update")
+    check(levels_off <= FSFL_LEVELS_TOL,
+          f"fsfl_round: {levels_off} of the nonzero levels differ > "
+          f"{FSFL_LEVELS_TOL}")
+    check(scales_err <= FSFL_SCALES_TOL,
+          f"fsfl_round: scale update relative L2 {scales_err} > "
+          f"{FSFL_SCALES_TOL}")
+
+
 def phase_engine(name: str, scenario, model, splits, rounds: int,
                  seed: int) -> None:
     import jax
@@ -263,9 +342,7 @@ def phase_engine(name: str, scenario, model, splits, rounds: int,
 
     clock = CompileClock()
     jax.monitoring.register_event_duration_secs_listener(clock)
-    before = (comms_device.dispatch_count(),
-              comms_device.interpreted_count(),
-              comms_device.handover_count())
+    before = (comms_device.dispatch_count(), comms_device.handover_count())
     t0 = time.perf_counter()
     try:
         res = run_scenario(scenario, rounds=rounds, model=model,
@@ -273,10 +350,9 @@ def phase_engine(name: str, scenario, model, splits, rounds: int,
     finally:
         jax.monitoring.unregister_event_duration_listener(clock)
     wall = time.perf_counter() - t0
-    dispatches, interpreted, handovers = (
-        comms_device.dispatch_count() - before[0],
-        comms_device.interpreted_count() - before[1],
-        comms_device.handover_count() - before[2])
+    dispatches, handovers = (comms_device.dispatch_count() - before[0],
+                             comms_device.handover_count() - before[1])
+    interpret = platform.interpret()
     for r in res.records:
         emit(name, round=r.round, wall_s=r.wall_s, up_bytes=r.up_bytes,
              test_acc=r.test_acc, train_loss=r.train_loss)
@@ -285,8 +361,8 @@ def phase_engine(name: str, scenario, model, splits, rounds: int,
     acc_bound = chance + 3.0 * math.sqrt(chance * (1 - chance) / n_test)
     emit(name, compile_s=clock.seconds, compiles=clock.count, run_s=wall,
          kernel_dispatches=dispatches,
-         compiled_dispatches=dispatches - interpreted,
-         host_handovers=handovers, interpret=platform.interpret(),
+         compiled_dispatches=0 if interpret else dispatches,
+         host_handovers=handovers, interpret=interpret,
          final_acc=res.final_acc, acc_bound=acc_bound)
     check(all(math.isfinite(r.train_loss) for r in res.records),
           f"{name}: non-finite training loss")
@@ -295,8 +371,7 @@ def phase_engine(name: str, scenario, model, splits, rounds: int,
     check(res.final_acc > acc_bound,
           f"{name}: final accuracy {res.final_acc} not above chance "
           f"({acc_bound})")
-    check(not platform.interpret() and interpreted == 0,
-          f"{name}: a kernel ran in the Pallas interpreter")
+    check(not interpret, f"{name}: the kernels ran in the Pallas interpreter")
     check(handovers == 0, f"{name}: {handovers} cohorts fell back to host "
           "encode")
     if scenario.device_encode:
@@ -331,11 +406,6 @@ def phase_sharded(model, splits, scenario, rounds: int, k: int, seed: int,
 def main(argv=None) -> int:
     ap = argparse.ArgumentParser(description=__doc__.split("\n\n")[0])
     ap.add_argument("--seed", type=int, default=0)
-    ap.add_argument("--rounds", type=int, default=3)
-    ap.add_argument("--clients", type=int, default=10)
-    ap.add_argument("--samples", type=int, default=6400,
-                    help="synthetic CIFAR-like images, split 70/15/15 into "
-                         "client train / client val / test")
     ap.add_argument("--chips", type=int, choices=(1, 4), default=1,
                     help="4: only the cohort-sharding phase, on 4 chips")
     args = ap.parse_args(argv)
@@ -366,29 +436,31 @@ def main(argv=None) -> int:
     emit("device", platform=dev.platform, kind=dev.device_kind,
          count=len(devices), jax=jax.__version__)
 
-    model, splits = make_task(args.seed, args.clients, args.samples)
+    model, splits = make_task(args.seed, CLIENTS, SAMPLES)
     n_params = sum(int(x.size) for x in
                    jax.tree.leaves(jax.eval_shape(model.init,
                                                   jax.random.PRNGKey(0))[0]))
-    emit("model", name=model.name, params=n_params, clients=args.clients,
+    emit("model", name=model.name, params=n_params, clients=CLIENTS,
          train_per_client=int(splits.client_x.shape[1]),
          test=int(splits.test_y.shape[0]))
     base = get_scenario("sync_full_fedavg_fsfl")
     if args.chips == 4:
-        phases = [lambda: phase_sharded(model, splits, base, args.rounds,
-                                        args.clients, args.seed, args.chips)]
+        phases = [lambda: phase_sharded(model, splits, base, ROUNDS, CLIENTS,
+                                        args.seed, args.chips)]
     else:
         device_int8 = dataclasses.replace(base, name="device_encode_int8_full",
                                           codec="int8-blockscale",
                                           device_encode=True)
         phases = [
-            lambda: phase_kernels(n_params, args.clients, args.seed),
-            lambda: phase_client_round(model, splits, base, args.rounds, 2,
+            lambda: phase_kernels(n_params, CLIENTS, args.seed),
+            lambda: phase_client_round(model, splits, base, ROUNDS, 2,
                                        args.seed),
-            lambda: phase_engine("engine", base, model, splits, args.rounds,
+            lambda: phase_fsfl_round(model, splits, base, ROUNDS, 2,
+                                     args.seed),
+            lambda: phase_engine("engine", base, model, splits, ROUNDS,
                                  args.seed),
             lambda: phase_engine("device_encode", device_int8, model, splits,
-                                 args.rounds, args.seed),
+                                 ROUNDS, args.seed),
         ]
     failed = 0
     for phase in phases:     # every phase runs; any failure fails the smoke

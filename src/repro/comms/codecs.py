@@ -127,7 +127,7 @@ class Int8BlockScaleCodec(Codec):
 
     def _encode_body(self, upd: ClientUpdate, spec: WireSpec) -> bytes:
         # The per-leaf zero pad is wire LAYOUT, not a kernel requirement
-        # (the kernel wrapper pads ragged n itself): aligning every leaf to
+        # (the kernel pads ragged n itself): aligning every leaf to
         # a block boundary keeps each 128-block inside one tensor, so the
         # concatenated buffer quantizes to the same q/scale chunks as the
         # historical leaf-at-a-time dispatch — but in ONE kernel call per

@@ -32,12 +32,12 @@ with ``device_encode=on`` in tests/test_rounds.py).  A function returns
 ``None`` when a device invariant fails (e.g. the zigzag range guard); the
 uplink then hands that cohort to the host path.
 
-Three monotone counters make the path observable: ``dispatch_count()``
+Two monotone counters make the path observable: ``dispatch_count()``
 (fused device programs launched here; ``fl/rounds.Uplink`` differences it
-around each cohort into the ``uplink.kernel_dispatches`` metric),
-``interpreted_count()`` (those of them that ran a Pallas kernel in the
-interpreter — 0 on a TPU) and ``handover_count()`` (cohorts handed back to
-the host path).
+around each cohort into the ``uplink.kernel_dispatches`` metric) and
+``handover_count()`` (cohorts handed back to the host path).  Whether the
+Pallas kernel runs compiled or interpreted is
+``repro.kernels.platform.interpret()``.
 
 This module is imported lazily (from the codecs' ``encode_cohort``
 overrides), so jax loads only when the device path is actually taken.
@@ -56,13 +56,11 @@ from repro.coding import nnc
 from repro.coding.bitstream import BitWriter
 from repro.comms.codec import (ClientUpdate, WireSpec, _cohort_size,
                                check_batch_clients, sorted_items)
-from repro.kernels import platform
 from repro.kernels.delta_compress import delta_compress_batch
 
 _ZIGZAG_SAFE = 2 ** 30   # |level| bound for exact int32 zigzag
 
 _dispatches = 0
-_interpreted = 0
 _handovers = 0
 
 
@@ -71,20 +69,14 @@ def dispatch_count() -> int:
     return _dispatches
 
 
-def interpreted_count() -> int:
-    """Dispatches whose Pallas kernel ran in the interpreter (monotone)."""
-    return _interpreted
-
-
 def handover_count() -> int:
     """Cohorts the device encoders handed back to the host path (monotone)."""
     return _handovers
 
 
-def _dispatched(interpreted: bool = False) -> None:
-    global _dispatches, _interpreted
+def _dispatched() -> None:
+    global _dispatches
     _dispatches += 1
-    _interpreted += int(interpreted)
 
 
 def count_handover() -> None:
@@ -124,8 +116,8 @@ def _frame_row(codec, body: bytes, bn_host: Any, i: int,
 # int8-blockscale
 # ===========================================================================
 
-@functools.partial(jax.jit, static_argnames=("block", "interpret"))
-def _int8_program(leaves, block: int, interpret: bool):
+@functools.partial(jax.jit, static_argnames=("block",))
+def _int8_program(leaves, block: int):
     """Pad each stacked leaf to a block multiple, concat, ONE batched
     sparsify+quantize dispatch over the (K, P) cohort buffer."""
     k = leaves[0].shape[0]
@@ -137,7 +129,7 @@ def _int8_program(leaves, block: int, interpret: bool):
             f = jnp.pad(f, ((0, 0), (0, pad)))
         flats.append(f)
     buf = jnp.concatenate(flats, axis=1)
-    return delta_compress_batch(buf, 0.0, block=block, interpret=interpret)
+    return delta_compress_batch(buf, 0.0, block=block)
 
 
 def int8_encode_cohort(codec, out: Any, spec: WireSpec, *,
@@ -152,10 +144,8 @@ def int8_encode_cohort(codec, out: Any, spec: WireSpec, *,
     padded = [n + (-n) % block for n in sizes]
     nblks = [p // block for p in padded]
     if sum(padded):
-        interpret = platform.interpret()
-        q, s = _int8_program(tuple(l for _, l in p_items), block=block,
-                             interpret=interpret)
-        _dispatched(interpret)
+        q, s = _int8_program(tuple(l for _, l in p_items), block=block)
+        _dispatched()
     else:
         q = np.zeros((k, 0), np.int8)
         s = np.zeros((k, 0), np.float32)

@@ -52,6 +52,7 @@ from __future__ import annotations
 
 import dataclasses
 import multiprocessing
+import warnings
 from concurrent.futures import ProcessPoolExecutor, ThreadPoolExecutor
 from typing import Any
 
@@ -61,6 +62,7 @@ import numpy as np
 
 from repro import comms
 from repro.comms import device as comms_device
+from repro.comms.codecs import Int8BlockScaleCodec
 from repro.coding.nnc import leaves_with_paths
 from repro.core import delta as delta_lib
 from repro.core import prand
@@ -76,6 +78,7 @@ from repro.fl.sampling import (EmptyCohortError, SamplingConfig,
                                sample_available, sample_cohort,
                                stream_cohort)
 from repro.fl.server_opt import server_update
+from repro.kernels import platform
 from repro.obs import metrics as obs_metrics
 from repro.obs import trace as obs_trace
 from repro.optim import apply_updates
@@ -564,6 +567,14 @@ class Uplink:
                 # deadlock the child.  The forkserver process is spawned
                 # clean (fork+exec) and workers fork from IT; preloading
                 # repro.comms there amortises the import across workers.
+                if (isinstance(self.codec, Int8BlockScaleCodec)
+                        and not platform.interpret()):
+                    warnings.warn(
+                        "uplink_executor='process' on a TPU: the workers "
+                        "run JAX on the CPU (the chip belongs to this "
+                        "process), so int8-blockscale encodes in the "
+                        "Pallas interpreter there", RuntimeWarning,
+                        stacklevel=2)
                 ctx = multiprocessing.get_context("forkserver")
                 ctx.set_forkserver_preload(["repro.comms"])
                 self._ex = ProcessPoolExecutor(

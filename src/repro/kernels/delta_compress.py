@@ -16,7 +16,7 @@ its out-of-range rows are never written back).  Scalars (theta, coef)
 ride in SMEM.
 
   * ``delta_compress`` — the per-client ``(n,)`` variant.  Ragged ``n``
-    is zero-padded INSIDE the jitted wrapper to a block multiple; padded
+    is zero-padded INSIDE the jitted function to a block multiple; padded
     lanes quantize to 0 and an all-pad block gets the scale-1 sentinel
     the host layout pins.
   * ``delta_compress_batch`` — the cohort variant over stacked ``(K, n)``
@@ -88,15 +88,6 @@ def _compress_blocks(blocks: jax.Array, theta, interpret: bool):
 
 
 @functools.partial(jax.jit, static_argnames=("block", "interpret"))
-def _delta_compress(delta, theta, *, block: int, interpret: bool):
-    n = delta.shape[0]
-    if n == 0:
-        return (jnp.zeros((0,), jnp.int8), jnp.zeros((0,), jnp.float32))
-    flat = jnp.pad(delta, (0, (-n) % block))
-    q, scales = _compress_blocks(flat.reshape(-1, block), theta, interpret)
-    return q.reshape(-1)[:n], scales
-
-
 def delta_compress(delta: jax.Array, theta, *, block: int = 1024,
                    interpret: bool | None = None):
     """delta: (n,) for ANY n (padded device-side); theta: scalar (Eq. 2).
@@ -104,23 +95,18 @@ def delta_compress(delta: jax.Array, theta, *, block: int = 1024,
     Returns (q int8 (n,), scales f32 (ceil(n/block),)).  The scale of a
     trailing partial block is computed over the zero-padded block — zeros
     never win the amax, so it equals the unpadded block's scale.
-    ``interpret=None`` takes ``platform.interpret()``.
+    ``interpret=None`` takes ``platform.interpret()`` when traced.
     """
-    return _delta_compress(delta, theta, block=block,
-                           interpret=platform.resolve(interpret))
+    n = delta.shape[0]
+    if n == 0:
+        return (jnp.zeros((0,), jnp.int8), jnp.zeros((0,), jnp.float32))
+    flat = jnp.pad(delta, (0, (-n) % block))
+    q, scales = _compress_blocks(flat.reshape(-1, block), theta,
+                                 platform.resolve(interpret))
+    return q.reshape(-1)[:n], scales
 
 
 @functools.partial(jax.jit, static_argnames=("block", "interpret"))
-def _delta_compress_batch(deltas, theta, *, block: int, interpret: bool):
-    k, n = deltas.shape
-    if n == 0 or k == 0:
-        return (jnp.zeros((k, 0), jnp.int8), jnp.zeros((k, 0), jnp.float32))
-    flat = jnp.pad(deltas, ((0, 0), (0, (-n) % block)))
-    p = flat.shape[1]
-    q, scales = _compress_blocks(flat.reshape(-1, block), theta, interpret)
-    return q.reshape(k, p)[:, :n], scales.reshape(k, p // block)
-
-
 def delta_compress_batch(deltas: jax.Array, theta, *, block: int = 128,
                          interpret: bool | None = None):
     """Cohort variant: deltas (K, n) for ANY n, ONE pallas dispatch.
@@ -128,8 +114,14 @@ def delta_compress_batch(deltas: jax.Array, theta, *, block: int = 128,
     Returns (q int8 (K, n), scales f32 (K, ceil(n/block))), row i byte-equal
     to ``delta_compress(deltas[i], theta, block=block)``.
     """
-    return _delta_compress_batch(deltas, theta, block=block,
-                                 interpret=platform.resolve(interpret))
+    k, n = deltas.shape
+    if n == 0 or k == 0:
+        return (jnp.zeros((k, 0), jnp.int8), jnp.zeros((k, 0), jnp.float32))
+    flat = jnp.pad(deltas, ((0, 0), (0, (-n) % block)))
+    p = flat.shape[1]
+    q, scales = _compress_blocks(flat.reshape(-1, block), theta,
+                                 platform.resolve(interpret))
+    return q.reshape(k, p)[:, :n], scales.reshape(k, p // block)
 
 
 def _apply_kernel(coef_ref, w_ref, q_ref, s_ref, o_ref):
@@ -139,7 +131,14 @@ def _apply_kernel(coef_ref, w_ref, q_ref, s_ref, o_ref):
 
 
 @functools.partial(jax.jit, static_argnames=("block", "interpret"))
-def _delta_apply(w, q, scales, coef, *, block: int, interpret: bool):
+def delta_apply(w: jax.Array, q: jax.Array, scales: jax.Array,
+                coef=1.0, *, block: int = 1024,
+                interpret: bool | None = None) -> jax.Array:
+    """Fused dequantize + apply: returns w + coef * (q * scale).
+
+    Accepts ANY n (padded device-side); scales has ceil(n/block) entries —
+    the layout ``delta_compress`` emits.
+    """
     n = w.shape[0]
     if q.shape != (n,):
         raise ValueError(f"q has shape {q.shape}, want ({n},)")
@@ -158,19 +157,7 @@ def _delta_apply(w, q, scales, coef, *, block: int, interpret: bool):
                   pl.BlockSpec((rows, 1), lambda i: (i, 0))],
         out_specs=tile,
         out_shape=jax.ShapeDtypeStruct((nblk, block), w.dtype),
-        interpret=interpret,
+        interpret=platform.resolve(interpret),
     )(_scalar(coef), jnp.pad(w, (0, pad)).reshape(nblk, block),
       jnp.pad(q, (0, pad)).reshape(nblk, block), scales.reshape(nblk, 1))
     return out.reshape(-1)[:n]
-
-
-def delta_apply(w: jax.Array, q: jax.Array, scales: jax.Array,
-                coef=1.0, *, block: int = 1024,
-                interpret: bool | None = None) -> jax.Array:
-    """Fused dequantize + apply: returns w + coef * (q * scale).
-
-    Accepts ANY n (padded device-side); scales has ceil(n/block) entries —
-    the layout ``delta_compress`` emits.
-    """
-    return _delta_apply(w, q, scales, coef, block=block,
-                        interpret=platform.resolve(interpret))

@@ -48,8 +48,14 @@ def _level_assign_kernel(theta_ref, step_ref, d_ref, r_ref, lv_ref, c_ref,
 
 
 @functools.partial(jax.jit, static_argnames=("max_level", "interpret"))
-def _level_assign(deltas, residuals, theta, step, *, max_level: int,
-                  interpret: bool):
+def level_assign(deltas: jax.Array, residuals: jax.Array, theta, step, *,
+                 max_level: int = 2**23, interpret: bool | None = None):
+    """Fused EF-carry → sparsify → quantize over stacked (K, n) deltas.
+
+    Returns ``(levels int32 (K, n), carry f32 (K, n))`` in ONE dispatch.
+    ``theta``/``step`` are scalars shared across the cohort (the engine's
+    per-tensor step sizes dispatch one call per step group).
+    """
     k, n = deltas.shape
     if residuals.shape != (k, n):
         raise ValueError(f"residuals have shape {residuals.shape}, "
@@ -67,21 +73,7 @@ def _level_assign(deltas, residuals, theta, step, *, max_level: int,
         out_specs=[tile, tile],
         out_shape=[jax.ShapeDtypeStruct((k, n), jnp.int32),
                    jax.ShapeDtypeStruct((k, n), jnp.float32)],
-        interpret=interpret,
+        interpret=platform.resolve(interpret),
     )(jnp.reshape(jnp.asarray(theta, jnp.float32), (1,)),
       jnp.reshape(jnp.asarray(step, jnp.float32), (1,)),
       deltas, residuals)
-
-
-def level_assign(deltas: jax.Array, residuals: jax.Array, theta, step, *,
-                 max_level: int = 2**23, interpret: bool | None = None):
-    """Fused EF-carry → sparsify → quantize over stacked (K, n) deltas.
-
-    Returns ``(levels int32 (K, n), carry f32 (K, n))`` in ONE dispatch.
-    ``theta``/``step`` are scalars shared across the cohort (the engine's
-    per-tensor step sizes dispatch one call per step group).
-    """
-    levels, carry = _level_assign(deltas, residuals, theta, step,
-                                  max_level=max_level,
-                                  interpret=platform.resolve(interpret))
-    return levels, carry

@@ -408,6 +408,29 @@ def test_int8_codec_is_fork_safe_now(tiny2):
             == [r.up_bytes for r in base.records])
 
 
+@pytest.mark.parametrize("tpu_parent", [False, True])
+def test_process_pool_warns_on_a_tpu_parent(tiny2, monkeypatch, tpu_parent):
+    """The pool's workers run JAX on the CPU, so under a TPU parent an
+    int8-blockscale encode there runs the Pallas kernel interpreted: the
+    uplink warns when it starts that pool, and only then."""
+    import warnings
+
+    from repro.core.protocol import make_protocol
+    from repro.kernels import platform
+
+    model, _ = tiny2
+    cfg = ProtocolConfig(name="fedavg", method="none", quantize=False)
+    server, _ = make_protocol(model, cfg, 1)[0](jax.random.PRNGKey(0))
+    monkeypatch.setattr(platform, "interpret", lambda: not tpu_parent)
+    up = Uplink(cfg, EngineConfig(codec="int8-blockscale", uplink_workers=2,
+                                  uplink_executor="process"), server)
+    with warnings.catch_warnings(record=True) as caught:
+        warnings.simplefilter("always")
+        up._executor().shutdown()
+    said = [w for w in caught if "Pallas interpreter" in str(w.message)]
+    assert len(said) == int(tpu_parent)
+
+
 # ------------------------------------------------------------- device encode
 
 @pytest.mark.parametrize("name", ["fsfl", "stc", "fedavg_nnc"])
@@ -759,7 +782,7 @@ def test_sync_scheduler_empty_cohort_is_all_drop_round(tiny2):
 def test_device_encode_counts_host_handovers(tiny2):
     """Every cohort the device path hands back to the host encoder is
     counted (a chip run asserts 0); a codec with a fast path hands none
-    over, and off a TPU its kernel dispatch is counted as interpreted."""
+    over and launches one device dispatch instead."""
     from repro.comms import device as comms_device
 
     model, splits = tiny2
@@ -767,8 +790,8 @@ def test_device_encode_counts_host_handovers(tiny2):
                          batch_size=32, local_lr=2e-3)
     for codec, handed in (("raw-fp32", 1), ("int8-blockscale", 0)):
         before = (comms_device.handover_count(),
-                  comms_device.interpreted_count())
+                  comms_device.dispatch_count())
         run_simulation(model, cfg, splits, 1, jax.random.PRNGKey(7),
                        engine=EngineConfig(device_encode=True, codec=codec))
         assert comms_device.handover_count() - before[0] == handed
-        assert comms_device.interpreted_count() - before[1] == 1 - handed
+        assert comms_device.dispatch_count() - before[1] == 1 - handed

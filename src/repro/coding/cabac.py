@@ -173,12 +173,19 @@ class Decoder:
         the probability state, range and code walk the identical sequence —
         but with all coder state in locals, so the per-bin cost is a
         fraction of the method-dispatch + numpy-scalar-indexing reference
-        path.  Returns a uint8 array of the decoded bits.
+        path.  Returns a uint8 array of the decoded bits.  Spanned as
+        ``cabac.decode`` with ``bins=n`` (either engine).
         """
         if n <= 0:
             return np.zeros(0, np.uint8)
-        if self.speculative:
-            return self._decode_bits_spec(ctxs, idx, n)
+        with obs_trace.span("cabac.decode", bins=n):
+            if self.speculative:
+                return self._decode_bits_spec(ctxs, idx, n)
+            return self._decode_bits_walk(ctxs, idx, n)
+
+    def _decode_bits_walk(self, ctxs: ContextSet, idx: int,
+                          n: int) -> np.ndarray:
+        """The per-bin walk of :meth:`decode_bits`, state in locals."""
         out = bytearray(n)
         p = int(ctxs.p[idx])
         rng = self.range

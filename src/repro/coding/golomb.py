@@ -18,6 +18,7 @@ from __future__ import annotations
 import numpy as np
 
 from repro.coding.bitstream import BitReader, BitWriter
+from repro.obs import trace as obs_trace
 
 _MAX_CODE_BITS = 63   # value bits fit int64; longer prefixes prove corruption
 
@@ -46,9 +47,15 @@ def encode_egk(writer: BitWriter, values: np.ndarray, k: int) -> None:
     Single-pass bit-matrix assembly: every codeword's value bits are
     extracted with one broadcast shift and scattered with one fancy-indexed
     store (the old per-bit-position loop paid numpy call overhead
-    ``nbits.max()`` times over)."""
+    ``nbits.max()`` times over).  Spanned as ``golomb.encode`` with
+    ``values=len(values)``."""
     if values.size == 0:
         return
+    with obs_trace.span("golomb.encode", values=len(values)):
+        _encode_egk(writer, values, k)
+
+
+def _encode_egk(writer: BitWriter, values: np.ndarray, k: int) -> None:
     v = values.astype(np.int64) + (1 << k)
     nbits = np.floor(np.log2(v)).astype(np.int64) + 1
     total = 2 * nbits - k - 1  # prefix (nbits-k-1 zeros) + nbits value bits
@@ -91,10 +98,16 @@ def decode_egk(reader: BitReader, count: int, k: int) -> np.ndarray:
     simple "next one" pointer would not do).  Phase 2 gathers all value
     bits in one fancy-indexed matrix multiply.  Bit-exact with
     :func:`decode_egk_ref`; raises ``EOFError`` on a truncated stream and
-    ``ValueError`` on codewords too long to be well-formed.
+    ``ValueError`` on codewords too long to be well-formed.  Spanned as
+    ``golomb.decode`` with ``values=count``.
     """
     if count == 0:
         return np.empty(0, np.int64)
+    with obs_trace.span("golomb.decode", values=count):
+        return _decode_egk_walk(reader, count, k)
+
+
+def _decode_egk_walk(reader: BitReader, count: int, k: int) -> np.ndarray:
     bits = reader.raw_bits
     nbits_total = bits.size
     # whole-stream set-bit index, built once per reader (immutable bits):
@@ -163,10 +176,16 @@ def decode_egk_jump(reader: BitReader, count: int, k: int) -> np.ndarray:
     falls out of consecutive starts (``z = (s + s' - k - 1) / 2``), so no
     per-codeword index walk remains.  Bit-exact with :func:`decode_egk`
     (same values, same cursor, same EOFError/ValueError surface); used by
-    the ``speculative`` NNC engine on large sections.
+    the ``speculative`` NNC engine on large sections.  Spanned as
+    ``golomb.decode`` with ``values=count``, like :func:`decode_egk`.
     """
     if count < _JUMP_MIN:
         return decode_egk(reader, count, k)
+    with obs_trace.span("golomb.decode", values=count):
+        return _decode_egk_orbit(reader, count, k)
+
+
+def _decode_egk_orbit(reader: BitReader, count: int, k: int) -> np.ndarray:
     bits = reader.raw_bits
     n = bits.size
     base = reader.jump_base()

@@ -206,26 +206,28 @@ def make_protocol(model: CNNModel, cfg: ProtocolConfig, steps_per_round: int):
             upd, opt_state = w_opt.update(grads, opt_state, params)
             return (apply_updates(params, upd), new_bn, opt_state), loss
 
-        (params1, bn1, opt_state1), losses = jax.lax.scan(
-            w_step, (params0, bn0, persistent.opt_state), batch_idx)
+        # stage scopes (HLO metadata only): the device profile attributes
+        # each operation to train_weights, compress or train_scales
+        with jax.named_scope("train_weights"):
+            (params1, bn1, opt_state1), losses = jax.lax.scan(
+                w_step, (params0, bn0, persistent.opt_state), batch_idx)
 
         # ---- 3. codec stages: delta + error feedback + sparsify + quant --
-        raw_delta = stages_lib.extract_delta(params1, params0)
-        carried = stages_lib.carry_residual(raw_delta, persistent.residual,
-                                            cfg.error_feedback)
-        levels, recon_delta, sparse_delta = up_stages.compress(carried,
-                                                               fine_mask)
-        new_residual = stages_lib.new_residual(carried, recon_delta,
-                                               cfg.error_feedback,
-                                               persistent.residual)
+        with jax.named_scope("compress"):
+            raw_delta = stages_lib.extract_delta(params1, params0)
+            carried = stages_lib.carry_residual(raw_delta, persistent.residual,
+                                                cfg.error_feedback)
+            levels, recon_delta, sparse_delta = up_stages.compress(carried,
+                                                                   fine_mask)
+            new_residual = stages_lib.new_residual(carried, recon_delta,
+                                                   cfg.error_feedback,
+                                                   persistent.residual)
 
-        # the sparsely updated model that S-training sees (Alg. 1 line 11)
-        params_hat = delta_lib.tree_add(params0, recon_delta)
+            # the sparsely updated model that S-training sees (Alg. 1 line 11)
+            params_hat = delta_lib.tree_add(params0, recon_delta)
 
         # ---- 4. scaling-factor sub-epochs (Alg. 1 lines 13-19) ----------
         if cfg.scaling:
-            perf0 = accuracy(params_hat, scales0, bn1, val_x, val_y)
-
             def s_loss(scales, x, y):
                 # BN frozen (train=False) and W frozen by construction
                 loss, _ = loss_fn(params_hat, scales, bn1, x, y, False)
@@ -250,9 +252,11 @@ def make_protocol(model: CNNModel, cfg: ProtocolConfig, steps_per_round: int):
                 best_perf = jnp.where(better, perf, best_perf)
                 return (scales, sopt, best_s, best_perf), perf
 
-            (scales_end, sopt1, best_s, best_perf), _ = jax.lax.scan(
-                sub_epoch, (scales0, persistent.scale_opt_state, scales0, perf0),
-                None, length=cfg.scale_subepochs)
+            with jax.named_scope("train_scales"):
+                perf0 = accuracy(params_hat, scales0, bn1, val_x, val_y)
+                (scales_end, sopt1, best_s, best_perf), _ = jax.lax.scan(
+                    sub_epoch, (scales0, persistent.scale_opt_state, scales0, perf0),
+                    None, length=cfg.scale_subepochs)
             scales1 = best_s  # == scales0 if no sub-epoch improved (discard rule)
             sopt_state1 = sopt1
         else:
@@ -262,9 +266,10 @@ def make_protocol(model: CNNModel, cfg: ProtocolConfig, steps_per_round: int):
             best_perf = perf0
 
         # ---- 5. quantize the S delta (fine step size) --------------------
-        s_delta = delta_lib.tree_sub(scales1, scales0)
-        s_levels, s_recon = stages_lib.quantize_scales_delta(
-            s_delta, cfg.fine_step_size)
+        with jax.named_scope("compress"):
+            s_delta = delta_lib.tree_sub(scales1, scales0)
+            s_levels, s_recon = stages_lib.quantize_scales_delta(
+                s_delta, cfg.fine_step_size)
 
         metrics = {
             "train_loss": jnp.mean(losses),

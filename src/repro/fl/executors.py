@@ -116,15 +116,15 @@ class SerialExecutor(ClientExecutor):
         self.jround = jax.jit(_named(client_round))
 
     def run_shared(self, server, pers, cx, cy, cvx, cvy, bidx):
-        with obs_trace.device_span("executor.run_shared", backend=self.name,
-                                   n=int(cx.shape[0])):
+        with obs_trace.span("executor.run_shared", backend=self.name,
+                            n=int(cx.shape[0])):
             return _stack([self.jround(server, _row(pers, i), cx[i], cy[i],
                                        cvx[i], cvy[i], bidx[i])
                            for i in range(cx.shape[0])])
 
     def run_stacked(self, servers, pers, cx, cy, cvx, cvy, bidx):
-        with obs_trace.device_span("executor.run_stacked", backend=self.name,
-                                   n=int(cx.shape[0])):
+        with obs_trace.span("executor.run_stacked", backend=self.name,
+                            n=int(cx.shape[0])):
             return _stack([self.jround(_row(servers, i), _row(pers, i),
                                        cx[i], cy[i], cvx[i], cvy[i], bidx[i])
                            for i in range(cx.shape[0])])
@@ -147,13 +147,13 @@ class VmapExecutor(ClientExecutor):
         self.vround_stacked = jax.jit(jax.vmap(named, **_STACKED_AXES))
 
     def run_shared(self, server, pers, cx, cy, cvx, cvy, bidx):
-        with obs_trace.device_span("executor.run_shared", backend=self.name,
-                                   n=int(cx.shape[0])):
+        with obs_trace.span("executor.run_shared", backend=self.name,
+                            n=int(cx.shape[0])):
             return self.vround(server, pers, cx, cy, cvx, cvy, bidx)
 
     def run_stacked(self, servers, pers, cx, cy, cvx, cvy, bidx):
-        with obs_trace.device_span("executor.run_stacked", backend=self.name,
-                                   n=int(cx.shape[0])):
+        with obs_trace.span("executor.run_stacked", backend=self.name,
+                            n=int(cx.shape[0])):
             return self.vround_stacked(servers, pers, cx, cy, cvx, cvy, bidx)
 
 
@@ -193,16 +193,16 @@ class ShardedExecutor(VmapExecutor):
 
     def run_shared(self, server, pers, cx, cy, cvx, cvy, bidx):
         n = cx.shape[0]
-        with obs_trace.device_span("executor.run_shared", backend=self.name,
-                                   n=int(n)):
+        with obs_trace.span("executor.run_shared", backend=self.name,
+                            n=int(n)):
             batch = self._padded((pers, cx, cy, cvx, cvy, bidx), n)
             out = self.vround(self._place(server, self._replicated), *batch)
             return _row(out, slice(0, n))
 
     def run_stacked(self, servers, pers, cx, cy, cvx, cvy, bidx):
         n = cx.shape[0]
-        with obs_trace.device_span("executor.run_stacked", backend=self.name,
-                                   n=int(n)):
+        with obs_trace.span("executor.run_stacked", backend=self.name,
+                            n=int(n)):
             servers, *batch = self._padded(
                 (servers, pers, cx, cy, cvx, cvy, bidx), n)
             out = self.vround_stacked(servers, *batch)
@@ -310,16 +310,16 @@ class DistExecutor(ShardedExecutor):
 
     def run_shared(self, server, pers, cx, cy, cvx, cvy, bidx):
         n = cx.shape[0]
-        with obs_trace.device_span("executor.run_shared", backend=self.name,
-                                   n=int(n)):
+        with obs_trace.span("executor.run_shared", backend=self.name,
+                            n=int(n)):
             batch = self._padded((pers, cx, cy, cvx, cvy, bidx), n)
             out = self.vround(self._place(server, self._replicated), *batch)
             return _row(self._fetch(out), slice(0, n))
 
     def run_stacked(self, servers, pers, cx, cy, cvx, cvy, bidx):
         n = cx.shape[0]
-        with obs_trace.device_span("executor.run_stacked", backend=self.name,
-                                   n=int(n)):
+        with obs_trace.span("executor.run_stacked", backend=self.name,
+                            n=int(n)):
             servers, *batch = self._padded(
                 (servers, pers, cx, cy, cvx, cvy, bidx), n)
             out = self.vround_stacked(servers, *batch)

@@ -63,7 +63,6 @@ import numpy as np
 from repro import comms
 from repro.comms import device as comms_device
 from repro.comms.codecs import Int8BlockScaleCodec
-from repro.coding.nnc import leaves_with_paths
 from repro.core import delta as delta_lib
 from repro.core import prand
 from repro.core import quant as quant_lib
@@ -302,7 +301,6 @@ class LocalTrain:
                 out = self.executor.run_shared(server, pers_c, cx, cy,
                                                cvx, cvy, batch_idx)
                 self.store.scatter(idx, out.persistent)
-            self._record_update_metrics(out)
             return out
 
     def train_window(self, kbs: list[jax.Array], clients: list[int],
@@ -334,33 +332,7 @@ class LocalTrain:
             else:
                 out = self.executor.run_stacked(stack_trees(servers), *args)
             self.store.scatter(idx, out.persistent)
-            self._record_update_metrics(out)
             return out
-
-    def _record_update_metrics(self, out) -> None:
-        """Per-layer sparsity of the decoded cohort update and Eq.-5
-        residual norms — gauges only; a no-op (and no device fetch) unless
-        a metrics registry is active."""
-        m = obs_metrics.get_registry()
-        if not m.enabled:
-            return
-        with obs_trace.span("local_train.metrics"):
-            pers = getattr(out, "persistent", None)
-            recon, resid = jax.device_get((
-                getattr(out, "recon_delta_params", None),
-                getattr(pers, "residual", None) if pers is not None
-                else None))
-            if recon is not None:
-                for path, leaf in leaves_with_paths(recon):
-                    arr = np.asarray(leaf)
-                    m.gauge(f"update.sparsity.{path}",
-                            float(np.mean(arr == 0.0)))
-            if resid is not None:
-                for path, leaf in leaves_with_paths(resid):
-                    arr = np.asarray(leaf, np.float64)
-                    flat = arr.reshape(arr.shape[0], -1)
-                    m.gauge(f"residual.norm.{path}",
-                            float(np.mean(np.linalg.norm(flat, axis=1))))
 
     def reinject_residual(self, client: int, delta: Any) -> None:
         """A dropped upload must not break Eq. 5: put the lost (decoded)
@@ -500,20 +472,25 @@ class Uplink:
         fetched only under schema v2, where it must be encoded; under v1
         it stays on device (contributions carry device rows and the BN
         mean never syncs to host, like the pre-redesign engine).  The
-        scalar metrics ride along for the Contribution metadata."""
+        scalar metrics ride along for the Contribution metadata.  The host
+        first waits for exactly those trees (``uplink.fetch.wait``), then
+        copies them (``uplink.fetch.copy``)."""
         with obs_trace.span("uplink.fetch"):
             return self._fetch(out)
 
     def _fetch(self, out):
         need_levels = "levels" in self.codec.needs
         need_recon = "recon" in self.codec.needs or self.spec.ternary
-        lp, ls, rp, rs, bn, metrics = jax.device_get((
-            out.levels_params if need_levels else None,
-            out.levels_scales if need_levels else None,
-            out.recon_delta_params if need_recon else None,
-            out.recon_delta_scales if need_recon else None,
-            out.bn_state if self.spec.version == 2 else None,
-            out.metrics))
+        trees = (out.levels_params if need_levels else None,
+                 out.levels_scales if need_levels else None,
+                 out.recon_delta_params if need_recon else None,
+                 out.recon_delta_scales if need_recon else None,
+                 out.bn_state if self.spec.version == 2 else None,
+                 out.metrics)
+        with obs_trace.span("uplink.fetch.wait"):
+            jax.block_until_ready(trees)
+        with obs_trace.span("uplink.fetch.copy"):
+            lp, ls, rp, rs, bn, metrics = jax.device_get(trees)
         upd = comms.ClientUpdate(lp, ls, rp, rs, bn=bn)
         return upd, metrics
 
@@ -914,7 +891,10 @@ class Evaluate:
 
     def __call__(self, server: ServerState) -> float:
         with obs_trace.span("evaluate"):
-            return float(self._eval(server, self.test_x, self.test_y))
+            acc = self._eval(server, self.test_x, self.test_y)
+            with obs_trace.span("evaluate.wait"):
+                acc.block_until_ready()
+            return float(acc)
 
 
 # ---------------------------------------------------------------- schedulers

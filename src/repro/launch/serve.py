@@ -58,9 +58,8 @@ def main(argv=None):
     prompts = jax.random.randint(jax.random.PRNGKey(2),
                                  (args.batch, args.prompt_len), 0, cfg.vocab)
     with tel.activate():
-        with obs_trace.device_span("serve.prefill", arch=args.arch,
-                                   batch=args.batch,
-                                   prompt_len=args.prompt_len):
+        with obs_trace.span("serve.prefill", arch=args.arch,
+                            batch=args.batch, prompt_len=args.prompt_len):
             nxt, cache = decode_lib.prefill(params, prompts, cfg, SINGLE,
                                             UNSHARDED,
                                             args.prompt_len + args.steps,
@@ -69,7 +68,7 @@ def main(argv=None):
             params, c, t, cfg, SINGLE, UNSHARDED))
         toks = [nxt]
         for i in range(args.steps - 1):
-            with obs_trace.device_span("serve.decode_step", step=i):
+            with obs_trace.span("serve.decode_step", step=i):
                 nxt, cache = step(cache, nxt)
             toks.append(nxt)
     for b in range(args.batch):

@@ -2,8 +2,8 @@
 
 ``repro.obs`` is the observability layer the FL engine threads through the
 whole round lifecycle (ISSUE 7 / ROADMAP bottleneck hunts): *when* each
-stage ran (``obs.trace`` spans, exported to JSONL or Chrome trace-event
-format for Perfetto) and *how much* it moved (``obs.metrics`` counters /
+stage ran (``obs.trace`` spans, on the ``jax.profiler`` timeline and
+exported in Chrome trace-event format for Perfetto) and *how much* it moved (``obs.metrics`` counters /
 gauges / histograms, snapshotted per round into ``RoundRecord.telemetry``).
 
 The two halves meet in :class:`Telemetry` — the bundle an engine owns:
@@ -39,14 +39,14 @@ from repro.obs import metrics, trace
 from repro.obs.metrics import (MetricsJsonlSink, MetricsRegistry,
                                NOOP_METRICS, NoopMetrics)
 from repro.obs.trace import (NOOP, NoopRecorder, Span, SpanRecorder,
-                             export_chrome_trace, export_jsonl)
+                             export_chrome_trace)
 
 __all__ = [
     "trace", "metrics",
     "Telemetry", "make_telemetry", "TELEMETRY_MODES",
     "Span", "SpanRecorder", "NoopRecorder", "NOOP",
     "MetricsRegistry", "NoopMetrics", "NOOP_METRICS", "MetricsJsonlSink",
-    "export_chrome_trace", "export_jsonl",
+    "export_chrome_trace",
 ]
 
 TELEMETRY_MODES = ("off", "metrics", "trace")
@@ -136,11 +136,6 @@ class Telemetry:
             return 0
         return export_chrome_trace(self.recorder.snapshot(), path,
                                    counters=self._counter_events())
-
-    def export_jsonl(self, path: str) -> int:
-        if self.recorder is NOOP:
-            return 0
-        return export_jsonl(self.recorder.snapshot(), path)
 
     def close(self) -> None:
         if self.sink is not None:

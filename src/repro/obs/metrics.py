@@ -2,8 +2,7 @@
 
 The registry is the numbers half of the telemetry subsystem (spans are the
 *when*, metrics are the *how much*): uplink/downlink bytes per codec
-section, per-layer update sparsity and Eq.-5 residual norms, store
-hot-shard occupancy and spill counts, pool task counts, dispatch-window
+section, store hot-shard occupancy and spill counts, pool task counts, dispatch-window
 batch fill, sim-vs-wall clock skew.
 
 Three instrument types, all thread-safe behind one registry lock (pooled

@@ -27,22 +27,26 @@ spawned by ``Uplink`` must inherit it, and contextvars do not cross
 another process and never see the parent recorder — their codec work is
 accounted parent-side at chunk granularity (documented in obs/README.md).
 
-Exporters
----------
-:func:`export_jsonl` writes one JSON object per span per line;
+Exporter
+--------
 :func:`export_chrome_trace` writes the Chrome trace-event format ("X"
 complete events, microsecond timestamps) that https://ui.perfetto.dev and
 chrome://tracing open directly.  Nesting needs no parent ids: Chrome infers
 it from interval containment per (pid, tid) track, which is exactly what a
 with-block guarantees.
 
-Device bridging
----------------
-:func:`device_span` pairs a host span with ``jax.profiler.TraceAnnotation``
-so the interval also shows up on the device timeline when a jax profiler
-session is active; the executors additionally wrap ``client_round`` in
-``jax.named_scope`` at bind time so compiled HLO carries the stage name.
-Both are gated on an active recorder — telemetry off never touches jax.
+The profiler bridge
+-------------------
+A live span also opens a ``jax.profiler.TraceAnnotation`` of the same name
+and attributes, so while a ``jax.profiler`` session runs every span lands
+on the profiler's host timeline (one line per thread), in the same file
+and on the same clock as the device operations; the attributes become the
+event's stats.  The recorder keeps its own ``perf_counter_ns`` interval,
+which differs from the profiler's by one constant offset
+(tests/test_obs.py).  The executors additionally wrap ``client_round`` in
+``jax.named_scope`` at bind time, and ``core.protocol.client_round`` names
+its stages, so compiled HLO carries the stage names.  Telemetry off never
+touches jax: :func:`span` returns the shared no-op first.
 """
 from __future__ import annotations
 
@@ -55,8 +59,7 @@ from typing import Any
 
 __all__ = [
     "Span", "SpanRecorder", "NoopRecorder", "NOOP",
-    "get_recorder", "use_recorder", "span", "device_span",
-    "export_jsonl", "export_chrome_trace",
+    "get_recorder", "use_recorder", "span", "export_chrome_trace",
 ]
 
 DEFAULT_RING = 65536
@@ -76,26 +79,32 @@ class Span:
         self.thread = thread
         self.attrs = attrs
 
-    def as_dict(self) -> dict[str, Any]:
-        d = {"name": self.name, "t0_ns": self.t0_ns, "dur_ns": self.dur_ns,
-             "thread": self.thread}
-        if self.attrs:
-            d["attrs"] = self.attrs
-        return d
-
     def __repr__(self) -> str:  # pragma: no cover - debugging nicety
         return (f"Span({self.name!r}, {self.dur_ns / 1e6:.3f} ms, "
                 f"thread={self.thread})")
 
 
+_annotation = None    # jax.profiler.TraceAnnotation, bound by the first live span
+
+
+def _trace_annotation():
+    global _annotation
+    if _annotation is None:
+        from jax.profiler import TraceAnnotation
+        _annotation = TraceAnnotation
+    return _annotation
+
+
 class _ActiveSpan:
     """The context manager one ``recorder.span()`` call returns.
 
-    Records at ``__exit__`` — children therefore land in the buffer BEFORE
-    their parent, which exporters and tests rely on (a parent's interval
+    Opens a ``jax.profiler.TraceAnnotation`` of the same name and
+    attributes around its own interval (the profiler bridge), and records
+    at ``__exit__`` — children therefore land in the buffer BEFORE their
+    parent, which exporters and tests rely on (a parent's interval
     strictly contains its children's)."""
 
-    __slots__ = ("_rec", "name", "attrs", "_t0")
+    __slots__ = ("_rec", "name", "attrs", "_t0", "_ann")
 
     def __init__(self, rec: "SpanRecorder", name: str,
                  attrs: dict[str, Any] | None):
@@ -104,11 +113,14 @@ class _ActiveSpan:
         self.attrs = attrs
 
     def __enter__(self) -> "_ActiveSpan":
+        self._ann = _trace_annotation()(self.name, **(self.attrs or {}))
+        self._ann.__enter__()
         self._t0 = time.perf_counter_ns()
         return self
 
     def __exit__(self, *exc) -> None:
         t1 = time.perf_counter_ns()
+        self._ann.__exit__(*exc)
         self._rec._record(Span(self.name, self._t0, t1 - self._t0,
                                threading.get_ident(), self.attrs))
 
@@ -221,53 +233,17 @@ def use_recorder(rec: SpanRecorder | NoopRecorder) -> _UseRecorder:
 
 
 def span(name: str, **attrs):
-    """Open a span on the ambient recorder (no-op when none is active)."""
+    """Open a span on the ambient recorder (no-op when none is active).
+
+    A live span also annotates the ``jax.profiler`` timeline; with no
+    recorder active this returns before touching jax or storing
+    ``attrs``."""
     if _active is NOOP:          # fast path: skip the attrs dict build
         return _NOOP_SPAN
     return _active.span(name, **attrs)
 
 
-class _DeviceSpan:
-    """Host span + ``jax.profiler.TraceAnnotation`` (active recorder only)."""
-
-    __slots__ = ("_span", "_ann")
-
-    def __init__(self, host_span: _ActiveSpan, name: str):
-        self._span = host_span
-        import jax
-        self._ann = jax.profiler.TraceAnnotation(name)
-
-    def __enter__(self):
-        self._span.__enter__()
-        self._ann.__enter__()
-        return self
-
-    def __exit__(self, *exc) -> None:
-        self._ann.__exit__(*exc)
-        self._span.__exit__(*exc)
-
-
-def device_span(name: str, **attrs):
-    """A span that also annotates the jax device timeline.
-
-    Telemetry off: returns the shared no-op WITHOUT importing or touching
-    jax — the off switch stays zero-cost even on the executor hot path.
-    """
-    if _active is NOOP:
-        return _NOOP_SPAN
-    return _DeviceSpan(_active.span(name, **attrs), name)
-
-
 # ---------------------------------------------------------------- exporters
-
-def export_jsonl(spans: list[Span], path: str) -> int:
-    """One JSON object per span per line (timeline order); returns count."""
-    ordered = sorted(spans, key=lambda s: s.t0_ns)
-    with open(path, "w") as f:
-        for s in ordered:
-            f.write(json.dumps(s.as_dict()) + "\n")
-    return len(ordered)
-
 
 def chrome_trace_events(spans: list[Span], *,
                         counters: list[dict[str, Any]] | None = None,

@@ -124,3 +124,45 @@ def test_sparse_structured_codes_smaller_than_dense():
     b_dense = len(nnc.encode_tree({"w": dense}))
     b_sparse = len(nnc.encode_tree({"w": sparse}))
     assert b_sparse < b_dense / 8
+
+
+# ------------------------------------------------------------- span counts
+
+def _spans_of(rec, name):
+    return [s for s in rec.snapshot() if s.name == name]
+
+
+@pytest.mark.parametrize("engine", ["vectorized", "speculative"])
+def test_decode_span_counts_match_the_encoder(engine):
+    """The decoder's ``cabac.decode`` spans count every context-coded bin
+    the encoder's pass 1 scanned, and ``golomb.decode`` every value
+    ``golomb.encode`` wrote — the counts the benchmark's time per bin
+    divides by.  The large tensor takes the speculative engine's jump
+    parse (over ``golomb._JUMP_MIN`` positions)."""
+    import jax
+
+    from repro.obs import trace as obs_trace
+
+    rng = np.random.default_rng(5)
+    tree = {"conv": (rng.integers(-40, 41, (64, 8, 3, 3))
+                     * (rng.random((64, 8, 3, 3)) < 0.3)).astype(np.int32),
+            "bias": rng.integers(-3, 4, (64,)).astype(np.int32),
+            "zero": np.zeros((8, 8), np.int32)}
+    rec = obs_trace.SpanRecorder()
+    with obs_trace.use_recorder(rec):
+        data = nnc.encode_tree(tree)
+        out = nnc.decode_tree(data, nnc.shapes_of(tree), engine=engine)
+    for a, b in zip(jax.tree.leaves(out), jax.tree.leaves(tree)):
+        np.testing.assert_array_equal(np.asarray(a), b)
+    (scan,) = _spans_of(rec, "cabac.pass1.state_scan")
+    dec = _spans_of(rec, "cabac.decode")
+    assert dec and sum(s.attrs["bins"] for s in dec) == scan.attrs["bins"]
+    enc_v = [s.attrs["values"] for s in _spans_of(rec, "golomb.encode")]
+    dec_v = [s.attrs["values"] for s in _spans_of(rec, "golomb.decode")]
+    assert sum(enc_v) == sum(dec_v) > golomb._JUMP_MIN
+    assert sorted(enc_v) == sorted(dec_v)
+    # every decode span sits inside the message's nnc.decode span
+    (msg,) = _spans_of(rec, "nnc.decode")
+    for s in dec + _spans_of(rec, "golomb.decode"):
+        assert msg.t0_ns <= s.t0_ns
+        assert s.t0_ns + s.dur_ns <= msg.t0_ns + msg.dur_ns

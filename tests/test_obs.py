@@ -4,6 +4,10 @@
   ring-buffer drop accounting, ambient activation, zero-cost off path,
 * export — the Chrome trace-event JSON is structurally valid (rebased
   monotone timeline, pid/tid on every event, counter tracks),
+* the profiler bridge — under ``jax.profiler`` every span is a host event
+  of the profile, one constant clock offset from the recorder; the host's
+  waits on the device are spans of their own; telemetry off opens none,
+  and ``"trace"`` fetches nothing from the device that ``"off"`` does not,
 * engine wiring — on the three seed-pin scenarios the per-round
   ``uplink.bytes``/``downlink.bytes`` counters equal the RoundRecord
   fields EXACTLY while the pinned byte totals still hold (telemetry is
@@ -215,7 +219,7 @@ def test_off_telemetry_is_inert(tmp_path):
         obs_metrics.count("ghost", 1)
         assert tel.round_snapshot(1) is None
     assert tel.export_chrome_trace(str(tmp_path / "e.json")) == 0
-    assert tel.export_jsonl(str(tmp_path / "e.jsonl")) == 0
+    assert tel.recorder is obs_trace.NOOP  # no span opens a profiler event
 
 
 # ------------------------------------------------------------- engine wiring
@@ -239,8 +243,8 @@ def test_engine_counters_equal_round_records_on_pins(tiny2, name):
         secs = {k: v for k, v in snap["counters"].items()
                 if k.startswith("uplink.section.")}
         assert sum(secs.values()) == rec.up_bytes  # anatomy covers the wire
-        assert any(k.startswith("update.sparsity.")
-                   for k in snap["gauges"])
+        assert not any(k.startswith(("update.sparsity.", "residual.norm."))
+                       for k in snap["gauges"])  # no per-leaf device fetch
 
 
 def test_async_windows_trace_and_batch_histogram(tiny2):
@@ -325,6 +329,128 @@ def test_device_encode_telemetry_off_is_deterministic(tiny2):
     assert "uplink.fetch" not in names  # the bulk fetch is gone
     snap = on.records[0].telemetry
     assert snap["counters"]["uplink.kernel_dispatches"] == 1
+
+
+# ------------------------------------------------------------- profiler bridge
+
+def _fsfl_cfg():
+    return ProtocolConfig(name="fsfl", batch_size=32, local_lr=2e-3,
+                          **_PINS["fsfl"]["cfg"])
+
+
+def _host_events(trace_dir) -> dict:
+    """{name: [(start_ns, stats)]} of every host event in the profile."""
+    import glob
+
+    from jax.profiler import ProfileData
+
+    (path,) = glob.glob(str(trace_dir / "**" / "*.xplane.pb"),
+                        recursive=True)
+    out: dict = {}
+    for plane in ProfileData.from_file(path).planes:
+        if plane.name.startswith("/host:"):
+            for line in plane.lines:
+                for e in line.events:
+                    out.setdefault(e.name, []).append(
+                        (e.start_ns, dict(e.stats)))
+    return out
+
+
+def test_spans_land_on_the_profiler_timeline(tiny2, tmp_path):
+    """Under ``jax.profiler`` every recorder span of a traced engine round
+    is also a host event of the same name and attributes, and the two
+    clocks differ by one constant offset: what lets a benchmark read the
+    program's spans on the device timeline."""
+    model, splits = tiny2
+    opts = jax.profiler.ProfileOptions()
+    opts.python_tracer_level = 0
+    opts.host_tracer_level = 1
+    jax.profiler.start_trace(str(tmp_path), profiler_options=opts)
+    try:
+        res = run_simulation(model, _fsfl_cfg(), splits, 1,
+                             jax.random.PRNGKey(7),
+                             engine=EngineConfig(telemetry="trace"))
+    finally:
+        jax.profiler.stop_trace()
+    events = _host_events(tmp_path)
+    by = _spans_by_name(res.telemetry.recorder)
+    assert {"round", "cabac.decode", "golomb.decode", "uplink.fetch.wait",
+            "evaluate.wait", "executor.run_shared"} <= set(by)
+    offsets = []
+    for name, spans in by.items():
+        evs = sorted(events.get(name, []), key=lambda e: e[0])
+        assert len(evs) == len(spans), name
+        for s, (start, stats) in zip(sorted(spans, key=lambda s: s.t0_ns),
+                                     evs):
+            offsets.append(s.t0_ns - start)
+            if name == "cabac.decode":
+                assert stats["bins"] == s.attrs["bins"]
+    assert max(offsets) - min(offsets) < 200_000  # ns
+
+
+def test_fetch_wait_and_copy_nest_in_the_fetch(tiny2):
+    """The host's wait on the device is its own span: ``uplink.fetch.wait``
+    then ``uplink.fetch.copy`` inside each ``uplink.fetch``, and
+    ``evaluate.wait`` inside ``evaluate``."""
+    model, splits = tiny2
+    res = run_simulation(model, _fsfl_cfg(), splits, 1,
+                         jax.random.PRNGKey(7),
+                         engine=EngineConfig(telemetry="trace"))
+    by = _spans_by_name(res.telemetry.recorder)
+
+    def inside(child, parent):
+        return (child.thread == parent.thread
+                and parent.t0_ns <= child.t0_ns
+                and child.t0_ns + child.dur_ns
+                <= parent.t0_ns + parent.dur_ns)
+
+    for outer, kids in (("uplink.fetch", ("uplink.fetch.wait",
+                                          "uplink.fetch.copy")),
+                        ("evaluate", ("evaluate.wait",))):
+        assert by[outer]
+        for parent in by[outer]:
+            got = [[c for c in by[k] if inside(c, parent)] for k in kids]
+            assert all(len(g) == 1 for g in got), (outer, got)
+            if len(got) == 2:  # the wait ends before the copy starts
+                (wait,), (copy,) = got
+                assert wait.t0_ns + wait.dur_ns <= copy.t0_ns
+
+
+def test_off_spans_touch_no_jax_and_trace_fetches_nothing_more(
+        tiny2, monkeypatch):
+    """Telemetry off: no span site opens a recorder span or a profiler
+    annotation.  Telemetry on: the same records, bit for bit, and no more
+    device-to-host fetches than off."""
+    model, splits = tiny2
+    fetches = []
+    real_get = jax.device_get
+
+    def counting_get(x):
+        fetches.append(1)
+        return real_get(x)
+
+    def refuse(*a, **k):
+        raise AssertionError("telemetry off opened a live span")
+
+    monkeypatch.setattr(jax, "device_get", counting_get)
+    runs = {}
+    for mode in ("off", "trace"):
+        with monkeypatch.context() as m:
+            if mode == "off":
+                m.setattr(obs_trace, "_trace_annotation", refuse)
+                m.setattr(obs_trace.SpanRecorder, "span", refuse)
+            fetches.clear()
+            res = run_simulation(model, _fsfl_cfg(), splits, 2,
+                                 jax.random.PRNGKey(7),
+                                 engine=EngineConfig(telemetry=mode))
+            runs[mode] = (res.records, len(fetches))
+    (off, n_off), (on, n_on) = runs["off"], runs["trace"]
+    assert n_on == n_off > 0
+    fields = [f.name for f in dataclasses.fields(RoundRecord)
+              if f.name not in ("telemetry", "wall_s")]
+    for a, b in zip(off, on):
+        assert [getattr(a, f) for f in fields] == \
+            [getattr(b, f) for f in fields]
 
 
 # ------------------------------------------------------------- codec anatomy

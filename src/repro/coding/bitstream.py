@@ -54,10 +54,11 @@ class BitWriter:
 class BitReader:
     def __init__(self, data: bytes) -> None:
         raw = np.frombuffer(data, np.uint8)
+        self._raw = raw
         self._bits = np.unpackbits(raw)
         self._pos = 0
-        self._ones: np.ndarray | None = None
-        self._csum: np.ndarray | None = None
+        self._words: np.ndarray | None = None
+        self._last_one: int | None = None
         self._jump: np.ndarray | None = None
         # composed exp-Golomb jump tables, keyed by order k: a multi-section
         # message reuses section 1's doubled table for every later section
@@ -85,25 +86,41 @@ class BitReader:
         return int(self._bits.size - self._pos)
 
     # -- block access (package-internal) ------------------------------------
-    # The vectorized exp-Golomb decoder (repro.coding.golomb.decode_egk)
-    # parses many codewords from the underlying bit array in one pass; it
-    # reads ``raw_bits``/``tell`` and commits its final cursor via ``seek``.
+    # The vectorised exp-Golomb parsers (repro.coding.golomb) read many
+    # codewords straight from the packed bytes; they read ``nbits``,
+    # ``words``/``last_one`` and ``tell``, and commit their final
+    # cursor via ``seek``.
 
     @property
-    def raw_bits(self) -> np.ndarray:
-        return self._bits
+    def nbits(self) -> int:
+        return int(self._bits.size)
 
-    def ones_index(self) -> tuple[np.ndarray, np.ndarray]:
-        """(set-bit positions, cumulative-ones prefix) over the WHOLE bit
-        array, built once per reader: the bits are immutable, and a
-        multi-section message would otherwise pay a full-stream rescan for
-        every exp-Golomb section it decodes."""
-        if self._ones is None:
-            self._ones = np.flatnonzero(self._bits)
-            csum = np.zeros(self._bits.size + 1, np.int64)
-            np.cumsum(self._bits, out=csum[1:])
-            self._csum = csum
-        return self._ones, self._csum
+    def words(self) -> np.ndarray:
+        """The 64 stream bits from every byte on, built once per reader:
+        ``words()[i]`` holds bytes ``i .. i + 7`` big-endian as one int64
+        (bit 63 is the first bit of byte ``i``; zeros past the end).  One
+        gather and one shift put the 57+ bits from any bit position in the
+        high bits of a word.  Entries run 8 bytes past the stream, so that
+        a codeword start a little past its end still indexes them."""
+        if self._words is None:
+            nbytes = self._raw.size
+            buf = np.zeros(nbytes + 16, np.uint8)
+            buf[:nbytes] = self._raw
+            self._words = np.ndarray((nbytes + 8,), ">u8", buf, 0,
+                                     (1,)).astype(np.int64)
+        return self._words
+
+    def last_one(self) -> int:
+        """Position of the stream's last set bit (-1 if none)."""
+        if self._last_one is None:
+            nz = np.flatnonzero(self._raw)
+            if nz.size:
+                b = int(nz[-1])
+                v = int(self._raw[b])       # its lowest set bit is last
+                self._last_one = 8 * b + 8 - (v & -v).bit_length()
+            else:
+                self._last_one = -1
+        return self._last_one
 
     def jump_base(self) -> np.ndarray:
         """k-independent exp-Golomb boundary-jump base, built once per reader.
@@ -115,19 +132,16 @@ class BitReader:
         slots ``q in (n, n+1)`` — hold ``n + 2`` so any order-k jump table
         derived from the base clamps them to the ``n + 1`` EOF fixed point.
         Shared by every exp-Golomb section of a message (the base does not
-        depend on the section's ``k``)."""
+        depend on the section's ``k``); built with array operations only,
+        as a reversed running minimum of the set-bit positions."""
         if self._jump is None:
             n = self._bits.size
-            ones, csum = self.ones_index()
-            base = np.full(n + 2, n + 2, np.int64)
-            if ones.size:
-                # positions past the last set bit have no next one — a
-                # contiguous dead tail, so no masking is needed up to it
-                live = int(ones[-1]) + 1
-                t = ones[csum[:live]]
-                t += t
-                t -= np.arange(live, dtype=np.int64)
-                base[:live] = t
+            pos = np.arange(n + 2, dtype=np.int64)
+            nxt = np.full(n + 2, n + 2, np.int64)
+            np.copyto(nxt[:n], pos[:n], where=self._bits.view(bool))
+            nxt = np.minimum.accumulate(nxt[::-1])[::-1]
+            base = nxt + nxt - pos
+            base[nxt == n + 2] = n + 2
             self._jump = base
         return self._jump
 

@@ -2,16 +2,17 @@
 
 DeepCABAC binarises quantization-level remainders with exp-Golomb codes whose
 bins are bypass-coded; STC's position coding is Golomb as well.  Encoding is
-fully vectorised (bit matrix assembly in numpy).  :func:`decode_egk` parses
-all ``count`` codewords in one pass over the underlying bit array: a cheap
-integer walk finds each codeword's boundary (O(1) per codeword via the
-cumulative-ones index), then one fancy-indexed gather extracts every value —
-this is the server-decode hot path under the vectorized NNC engine.
-:func:`decode_egk_jump` replaces that integer walk with a pointer-doubling
-orbit over a per-position jump table (``log2(count)`` dense gathers instead
-of ``count`` Python iterations) — the bypass half of the ``speculative``
-NNC engine.  :func:`decode_egk_ref` keeps the original bit-by-bit walk as
-the reference both fast parsers are differentially tested against.
+fully vectorised (bit matrix assembly in numpy).  :func:`decode_egk` is the
+server-decode hot path under the vectorized NNC engine: a large section
+finds its codeword boundaries by chunk synchronisation (speculative parses
+from every chunk start, advanced in lockstep, then joined where the true
+parse lands on one of them), a small one walks them in plain ints; either
+way every value is then read from a 64-bit window of the packed bytes in
+one pass.  :func:`decode_egk_jump` resolves the boundaries instead by a
+pointer-doubling orbit over a per-position jump table — the bypass half of
+the ``speculative`` NNC engine.  :func:`decode_egk_ref` keeps the original
+bit-by-bit walk as the reference both fast parsers are differentially
+tested against.
 """
 from __future__ import annotations
 
@@ -92,65 +93,272 @@ def decode_egk_ref(reader: BitReader, count: int, k: int) -> np.ndarray:
 def decode_egk(reader: BitReader, count: int, k: int) -> np.ndarray:
     """Vectorised order-k exp-Golomb decode of ``count`` values.
 
-    Phase 1 walks codeword boundaries with plain ints: the prefix of
-    codeword *i* ends at the first set bit at or after its start, found in
-    O(1) from the cumulative-ones index (value bits may contain ones, so a
-    simple "next one" pointer would not do).  Phase 2 gathers all value
-    bits in one fancy-indexed matrix multiply.  Bit-exact with
-    :func:`decode_egk_ref`; raises ``EOFError`` on a truncated stream and
-    ``ValueError`` on codewords too long to be well-formed.  Spanned as
-    ``golomb.decode`` with ``values=count``.
+    Finds the ``count + 1`` codeword boundaries (each start, then the end)
+    and extracts every value from the packed bytes in one pass
+    (:func:`_values`).  A section of ``_VECTOR_MIN`` codewords or more
+    finds its boundaries by chunk synchronisation (:func:`_bounds_vector`);
+    a shorter one walks them in plain Python ints (:func:`_bounds_scalar`),
+    where the vector set-up would cost more than it saves.  Bit-exact with
+    :func:`decode_egk_ref`: the same values and the same cursor afterwards.
+    Raises ``EOFError`` when the section runs past the stream and, failing
+    that, ``ValueError`` on a codeword of more than 63 value bits.  Spanned
+    as ``golomb.decode`` with ``values=count``, ``vector`` (1 where the
+    chunk synchronisation ran) and ``resync_steps`` (the codewords it
+    walked from chunk entries before they merged).
     """
     if count == 0:
         return np.empty(0, np.int64)
-    with obs_trace.span("golomb.decode", values=count):
-        return _decode_egk_walk(reader, count, k)
+    vector = count >= _VECTOR_MIN
+    with obs_trace.span("golomb.decode", values=count,
+                        vector=int(vector)) as sp:
+        if vector:
+            bounds, steps = _bounds_vector(reader, reader.tell(), count, k)
+        else:
+            bounds, steps = _bounds_scalar(reader, reader.tell(), count, k), 0
+        sp.set(resync_steps=steps)
+        v = _values(reader, bounds, k)
+        reader.seek(int(bounds[-1]))
+        return v
 
 
-def _decode_egk_walk(reader: BitReader, count: int, k: int) -> np.ndarray:
-    bits = reader.raw_bits
-    nbits_total = bits.size
-    # whole-stream set-bit index, built once per reader (immutable bits):
-    # csum[i] = ones in bits[:i] -> index into `ones` of the first set bit
-    # at position >= i
-    ones, csum = reader.ones_index()
-    starts = np.empty(count, np.int64)
-    nbits = np.empty(count, np.int64)
-    s = reader.tell()
-    try:
-        for i in range(count):
-            z = ones[csum[s]]           # first 1 at/after s ends the prefix
-            nb = (z - s) + k + 1
-            starts[i] = z
-            nbits[i] = nb
-            s = z + nb
-    except IndexError:
-        raise EOFError("bitstream exhausted") from None
-    if s > nbits_total:
+# sections of at least this many codewords take the chunk synchronisation;
+# below it the Python walk is cheaper than the vector set-up (measured on
+# realistic gap and remainder sections, see PERF.md)
+_VECTOR_MIN = 1024
+
+# chunk width in minimal codewords (k + 1 bits each): a speculative path
+# crosses its chunk in at most this many vector steps
+_CHUNK_CODES = 16
+
+# resync lanes left to the plain-int walk: below this many, a vector step
+# costs more than stepping each lane on its own
+_RESYNC_TAIL = 24
+
+# leading zeros of every 16-bit value, doubled (a codeword's prefix and as
+# many value bits again); 0 has no set bit and takes the far path
+_ZZ16 = 2 * (16 - np.frexp(np.arange(1 << 16, dtype=np.float64))[1]
+             ).astype(np.int64)
+
+_M64 = (1 << 64) - 1
+
+
+def _bounds_scalar(reader: BitReader, s: int, count: int,
+                   k: int) -> np.ndarray:
+    """The ``count + 1`` boundaries from ``s``, one codeword at a time."""
+    n = reader.nbits
+    item = reader.words().item
+    k1 = k + 1
+    out = [s]
+    for _ in range(count):
+        if s >= n:
+            raise EOFError("bitstream exhausted")
+        s = _next_scalar(reader, item, s, k1)
+        out.append(s)
+    if s > n:
         raise EOFError("bitstream exhausted")
-    v = _extract_values(bits, starts, nbits)
-    reader.seek(s)
-    return v - (1 << k)
+    return np.array(out, np.int64)
 
 
-def _extract_values(bits: np.ndarray, starts: np.ndarray,
-                    nbits: np.ndarray) -> np.ndarray:
-    """Phase 2: gather every codeword's MSB-first value bits in one
-    fancy-indexed matrix multiply (``starts`` point at each codeword's
-    first set bit).  Raises ``ValueError`` on codewords too long to be
-    well-formed."""
-    if nbits.size == 0:
+def _next_scalar(reader: BitReader, item, s: int, k1: int) -> int:
+    """The codeword end after start ``s`` in plain ints: the prefix ends at
+    the first set bit of the 64-bit window at ``s`` (``int.bit_length``),
+    and the codeword spans twice the prefix plus k + 1."""
+    w = (item(s >> 3) << (s & 7)) & _M64
+    if w:
+        return s + 2 * (64 - w.bit_length()) + k1
+    return int(_far_bounds(reader, np.array([s]), k1)[0])   # 57+ zeros
+
+
+def _step(reader: BitReader, p: np.ndarray, zz: np.ndarray,
+          k1: int) -> np.ndarray:
+    """Next codeword boundary after each position of ``p``; ``zz`` is
+    ``_ZZ16 + k + 1``.  A codeword that runs past the stream ends past
+    ``n``, and a start with no set bit after it gets ``n + 1``: either way
+    at or past the end of every window."""
+    w = reader.words().take(p >> 3)
+    w <<= p & 7
+    t = w.view(np.uint64)
+    t >>= np.uint64(48)
+    q = zz.take(t.view(np.int64))
+    q += p
+    if not t.all():                         # 16+ leading zeros
+        far = np.flatnonzero(t == 0)
+        q[far] = _far_bounds(reader, p[far], k1)
+    return q
+
+
+def _bit_length(w: np.ndarray) -> np.ndarray:
+    """Bit length of each uint64 (0 for 0), exact through float64."""
+    hi = np.frexp((w >> np.uint64(11)).astype(np.float64))[1]
+    lo = np.frexp((w & np.uint64(0x7FF)).astype(np.float64))[1]
+    return np.where(hi > 0, hi + 11, lo).astype(np.int64)
+
+
+def _far_bounds(reader: BitReader, p: np.ndarray, k1: int) -> np.ndarray:
+    """Codeword ends for starts whose first 16 bits are zero: the set bit
+    that ends the prefix is found word by word; a start past the stream's
+    last set bit has none and gets ``n + 1``."""
+    n = reader.nbits
+    words = reader.words()
+    out = np.full(p.size, n + 1, np.int64)
+    live = np.flatnonzero(p <= reader.last_one())
+    if not live.size:
+        return out
+    q = p[live]
+    w = (words[q >> 3] << (q & 7)).view(np.uint64)
+    one = q + 64 - _bit_length(w)           # valid where w != 0
+    byte = (q >> 3) + 8
+    rest = np.flatnonzero(w == 0)
+    while rest.size:                        # whole zero words: next word
+        w = words[byte[rest]].view(np.uint64)
+        bl = _bit_length(w)
+        got = bl > 0
+        one[rest[got]] = 8 * byte[rest[got]] + 64 - bl[got]
+        byte[rest] += 8
+        rest = rest[~got]
+    out[live] = np.minimum(2 * one - q + k1, n + 1)
+    return out
+
+
+def _bounds_vector(reader: BitReader, s0: int, count: int,
+                   k: int) -> tuple[np.ndarray, int]:
+    """The ``count + 1`` boundaries from ``s0`` by chunk synchronisation,
+    window by window (:func:`_window`), and the resync steps it took.  The
+    first window is sized for ``k + 2.25`` bits a codeword (a little over
+    the mean length at the order ``choose_k`` picks); the next, if the
+    boundaries ran short, from the bits a codeword the first one read.
+    No window reads past the stream; a parse that reaches its end first
+    raises ``EOFError``."""
+    n = reader.nbits
+    k1 = k + 1
+    chunk = _CHUNK_CODES * k1
+    zz = _ZZ16 + k1
+    parts, have, steps = [], 0, 0
+    s, per = s0, k1 + 1.25
+    while have < count + 1:
+        if s > n:
+            raise EOFError("bitstream exhausted")
+        need = count + 1 - have
+        e = min(s + int(need * per) + chunk, n + 1)
+        b, s, st = _window(reader, s, e, zz, k1, chunk)
+        parts.append(b)
+        have += b.size
+        steps += st
+        per = 1.25 * (s - s0) / have
+    bounds = parts[0] if len(parts) == 1 else np.concatenate(parts)
+    return bounds[:count + 1], steps
+
+
+def _window(reader: BitReader, s: int, e: int, zz: np.ndarray, k1: int,
+            chunk: int) -> tuple[np.ndarray, int, int]:
+    """Boundaries of the true parse from ``s`` inside ``[s, e)``.
+
+    Returns them in order, the true parse's first boundary at or past
+    ``e`` (where the next window starts) and the resync steps taken.
+
+    1. Every chunk start (``s``, ``s + chunk``, ...) starts a speculative
+       path; all advance in lockstep, one vector step per codeword, until
+       each has left its chunk.  A path's positions are its chunk's marks.
+    2. Each path then walks on from its exit, in lockstep, until it lands
+       on a mark (of whichever chunk: from there on it coincides with that
+       chunk's path) or leaves the window.  These are the resync steps.
+    3. The true parse starts on path 0.  Each path it follows hands it on
+       where it landed, to the path of the chunk it landed in, from that
+       mark on; the chain over chunks is resolved by doubling.  Its
+       boundaries are each followed path's marks from its entry on plus
+       that path's resync steps.
+    """
+    J = -(-(e - s) // chunk)
+    c = s + chunk * np.arange(J, dtype=np.int64)
+    end = np.minimum(c + chunk, e)
+    rows = [c]
+    p = c
+    for _ in range(_CHUNK_CODES):           # codewords are k + 1 bits or more
+        p = _step(reader, p, zz, k1)
+        rows.append(p)
+    P = np.stack(rows)                      # (steps + 1, J), rising per column
+    own = P < end
+    size = J * chunk + 1                    # the last slot stays unmarked
+    mark = np.zeros(size, bool)
+    mark[P[own] - s] = True
+
+    # resync: every path from its exit until it lands on a mark
+    lanes = np.arange(J)
+    q = P[own.sum(0), lanes]
+    land = np.empty(J, np.int64)
+    wl, wq = [], []
+    while lanes.size > _RESYNC_TAIL:
+        done = mark[np.minimum(q - s, size - 1)]
+        done |= q >= e
+        land[lanes[done]] = q[done]
+        keep = ~done
+        lanes, q = lanes[keep], q[keep]
+        wl.append(lanes)
+        wq.append(q)
+        q = _step(reader, q, zz, k1)
+    item, tl, tq = reader.words().item, [], []
+    for i, x in zip(lanes.tolist(), q.tolist()):
+        while x < e and not mark[x - s]:
+            tl.append(i)
+            tq.append(x)
+            x = _next_scalar(reader, item, x, k1)
+        land[i] = x
+    wl.append(np.array(tl, np.int64))
+    wq.append(np.array(tq, np.int64))
+
+    # the chain over paths: path i hands on to the chunk it landed in
+    nxt = np.append(np.where(land < e, (land - s) // chunk, J), J)
+    seq, f = np.zeros(1, np.int64), nxt
+    while seq[-1] != J:
+        seq = np.concatenate([seq, f[seq]])
+        f = f[f]
+    on = np.zeros(J + 1, bool)
+    on[seq] = True
+    on = on[:J]
+    # entry of each followed path: its marks before the hand-over drop
+    entry = np.full(J, s, np.int64)
+    src = np.flatnonzero(on & (nxt[:J] < J))
+    entry[nxt[src]] = land[src]
+    own &= P >= entry
+    own &= on
+    wl, wq = np.concatenate(wl), np.concatenate(wq)
+    wq = wq[on[wl]]
+    hit = np.zeros(size, bool)              # in order, without a sort
+    hit[P[own] - s] = True
+    hit[wq - s] = True
+    bounds = np.flatnonzero(hit)
+    bounds += s
+    last = int(seq[np.searchsorted(seq, J) - 1])
+    return bounds, int(land[last]), int(wq.size)
+
+
+def _values(reader: BitReader, bounds: np.ndarray, k: int) -> np.ndarray:
+    """Every codeword's value from its boundaries: codeword i's first set
+    bit is ``z = (b[i] + b[i + 1] - k - 1) / 2`` and its value bits run
+    from ``z`` to ``b[i + 1]``; they are read from the 64-bit window at
+    ``z``'s byte (plus the next byte where they pass its end).  Raises
+    ``ValueError`` on codewords too long to be well-formed."""
+    if bounds.size < 2:
         return np.zeros(0, np.int64)
-    maxnb = int(nbits.max())
-    if maxnb > _MAX_CODE_BITS:
-        raise ValueError(f"exp-Golomb codeword of {maxnb} bits (corrupt)")
-    # right-align every codeword's value bits so the bit weights are the
-    # same for every row and the ragged sum collapses into one matvec
-    cols = np.arange(maxnb)
-    idx = (starts + nbits)[:, None] + (cols - maxnb)[None, :]
-    valid = cols[None, :] >= (maxnb - nbits[:, None])
-    mat = bits[np.clip(idx, 0, bits.size - 1)] & valid
-    return mat.astype(np.int64) @ (np.int64(1) << (maxnb - 1 - cols))
+    t = bounds[1:]
+    z = (bounds[:-1] + t - (k + 1)) >> 1
+    nb = t - z
+    top = int(nb.max())
+    if top > _MAX_CODE_BITS:
+        raise ValueError(f"exp-Golomb codeword of {top} bits (corrupt)")
+    byte = z >> 3
+    off = z & 7
+    w = reader.words()[byte]
+    w <<= off
+    w = w.view(np.uint64)
+    if top > 57:
+        far = np.flatnonzero(off + nb > 64)
+        nxt = reader.words()[byte[far] + 8].view(np.uint64)
+        w[far] |= nxt >> (64 - off[far]).view(np.uint64)
+    w >>= (64 - nb).view(np.uint64)
+    v = w.view(np.int64)
+    v -= 1 << k
+    return v
 
 
 # below this count the jump decoder falls back to the sequential boundary
@@ -186,8 +394,7 @@ def decode_egk_jump(reader: BitReader, count: int, k: int) -> np.ndarray:
 
 
 def _decode_egk_orbit(reader: BitReader, count: int, k: int) -> np.ndarray:
-    bits = reader.raw_bits
-    n = bits.size
+    n = reader.nbits
     base = reader.jump_base()
     cached = reader.jump_pow.get(k)
     if cached is not None:
@@ -220,9 +427,6 @@ def _decode_egk_orbit(reader: BitReader, count: int, k: int) -> np.ndarray:
     s = int(starts[-1])
     if s > n:
         raise EOFError("bitstream exhausted")
-    # codeword i's first set bit: s_{i+1} = 2 z_i - s_i + k + 1, exactly
-    zs = (starts[:-1] + starts[1:] - (k + 1)) >> 1
-    nbits = starts[1:] - zs
-    v = _extract_values(bits, zs, nbits)
+    v = _values(reader, starts, k)
     reader.seek(s)
-    return v - (1 << k)
+    return v

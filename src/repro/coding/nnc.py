@@ -23,8 +23,10 @@ Two engines produce THE SAME bytes:
     state with the per-context numpy scan (``cabac.context_state_sequence``)
     and pass 2 is the single precomputed-probability range-coder loop
     (``cabac.range_encode_bins``).  Decode walks same-context bin blocks
-    through ``Decoder.decode_bits`` and parses exp-Golomb sections with the
-    vectorised ``golomb.decode_egk``.
+    through ``Decoder.decode_bits`` and parses exp-Golomb sections with
+    ``golomb.decode_egk``: chunk-synchronised codeword boundaries on large
+    sections, a plain-int walk on small ones, and every value read from a
+    64-bit window of the packed bytes.
   * ``engine="serial"``: the original one-call-per-bin reference coder.  It
     is the ORACLE the vectorized engine is differentially tested against
     (tests/test_cabac_differential.py) — kept runnable, never dead code.
@@ -266,8 +268,9 @@ def decode_tensor(shape: tuple, enc_dec: Decoder, ctx: ContextSet,
                   bypass: BitReader, jump: bool = False) -> np.ndarray:
     """Fast decode of one tensor: same-context bin blocks decode through
     ``Decoder.decode_bits`` (bit-exactly the reference per-bin walk) and
-    the exp-Golomb sections parse vectorised — under ``jump=True`` (the
-    speculative engine) via the pointer-doubling boundary walk."""
+    the exp-Golomb sections parse vectorised (``golomb.decode_egk``) —
+    under ``jump=True`` (the speculative engine) via the pointer-doubling
+    boundary walk."""
     egk = golomb.decode_egk_jump if jump else golomb.decode_egk
     ndim = len(shape)
     size = int(np.prod(shape)) if shape else 1

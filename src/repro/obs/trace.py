@@ -118,6 +118,12 @@ class _ActiveSpan:
         self._t0 = time.perf_counter_ns()
         return self
 
+    def set(self, **attrs) -> None:
+        """Attach attributes known only once the work has run; they reach
+        the recorded span and the profiler's event alike."""
+        self.attrs = {**(self.attrs or {}), **attrs}
+        self._ann.set_metadata(**attrs)
+
     def __exit__(self, *exc) -> None:
         t1 = time.perf_counter_ns()
         self._ann.__exit__(*exc)
@@ -132,6 +138,9 @@ class _NoopSpan:
 
     def __enter__(self) -> "_NoopSpan":
         return self
+
+    def set(self, **attrs) -> None:
+        pass
 
     def __exit__(self, *exc) -> None:
         pass

@@ -152,3 +152,33 @@ class BitReader:
         if not 0 <= pos <= self._bits.size:
             raise EOFError("bitstream exhausted")
         self._pos = pos
+
+
+class ReaderGroup:
+    """Several readers' streams in one address space, for a parser that
+    advances one section of each in lockstep (``golomb.decode_egk_many``).
+
+    Reader i's bit ``p`` is bit ``base[i] + p`` of the group, and
+    ``words`` is :meth:`BitReader.words` over all of them: each stream is
+    followed by 16 zero bytes, so a 64-bit window taken anywhere up to 64
+    bits past a stream's end reads that stream or zeros, never the next
+    stream.  ``ends[i]`` and ``last_ones[i]`` are reader i's ``nbits`` and
+    ``last_one()`` in group positions.  The readers keep their own cursors.
+    """
+
+    def __init__(self, readers) -> None:
+        self.readers = list(readers)
+        sizes = np.array([r._raw.size + 16 for r in self.readers], np.int64)
+        starts = np.cumsum(sizes) - sizes
+        buf = np.zeros(int(sizes.sum()) + 8, np.uint8)
+        for r, b in zip(self.readers, starts.tolist()):
+            buf[b:b + r._raw.size] = r._raw
+        self.words = np.ndarray((int(sizes.sum()),), ">u8", buf, 0,
+                                (1,)).astype(np.int64)
+        self.base = 8 * starts
+        self.ends = self.base + [r.nbits for r in self.readers]
+        self.last_ones = self.base + [r.last_one() for r in self.readers]
+
+    def stream_of(self, p: np.ndarray) -> np.ndarray:
+        """The stream each group position lies in."""
+        return np.searchsorted(self.base, p, "right") - 1

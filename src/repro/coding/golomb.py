@@ -8,17 +8,23 @@ finds its codeword boundaries by chunk synchronisation (speculative parses
 from every chunk start, advanced in lockstep, then joined where the true
 parse lands on one of them), a small one walks them in plain ints; either
 way every value is then read from a 64-bit window of the packed bytes in
-one pass.  :func:`decode_egk_jump` resolves the boundaries instead by a
-pointer-doubling orbit over a per-position jump table — the bypass half of
-the ``speculative`` NNC engine.  :func:`decode_egk_ref` keeps the original
+one pass.  :func:`decode_egk_many` runs the same parse over one section
+of each of several independent streams at once (a batch of messages laid
+side by side in a :class:`~repro.coding.bitstream.ReaderGroup`), so one
+set of array operations advances all of them.  :func:`decode_egk_jump`
+resolves the boundaries instead by a pointer-doubling orbit over a
+per-position jump table — the bypass half of the ``speculative`` NNC
+engine.  :func:`decode_egk_ref` keeps the original
 bit-by-bit walk as the reference both fast parsers are differentially
 tested against.
 """
 from __future__ import annotations
 
+from typing import Sequence
+
 import numpy as np
 
-from repro.coding.bitstream import BitReader, BitWriter
+from repro.coding.bitstream import BitReader, BitWriter, ReaderGroup
 from repro.obs import trace as obs_trace
 
 _MAX_CODE_BITS = 63   # value bits fit int64; longer prefixes prove corruption
@@ -109,16 +115,85 @@ def decode_egk(reader: BitReader, count: int, k: int) -> np.ndarray:
     if count == 0:
         return np.empty(0, np.int64)
     vector = count >= _VECTOR_MIN
-    with obs_trace.span("golomb.decode", values=count,
-                        vector=int(vector)) as sp:
+    with obs_trace.span("golomb.decode", values=count, vector=int(vector),
+                        streams=1, lockstep_values=0) as sp:
+        s = reader.tell()
         if vector:
-            bounds, steps = _bounds_vector(reader, reader.tell(), count, k)
+            bounds, steps = _bounds_vector(reader, s, count, k)
         else:
-            bounds, steps = _bounds_scalar(reader, reader.tell(), count, k), 0
+            bounds = _bounds_scalar(reader.words(), s, count, k + 1,
+                                    reader.nbits, reader.last_one())
+            steps = 0
         sp.set(resync_steps=steps)
-        v = _values(reader, bounds, k)
+        v = _values(reader.words(), bounds[:-1], bounds[1:], k)
         reader.seek(int(bounds[-1]))
         return v
+
+
+def decode_egk_many(group: ReaderGroup,
+                    asks: Sequence[tuple[int, int, int]]) -> list[np.ndarray]:
+    """One exp-Golomb section from each of several streams, parsed in
+    lockstep: ``asks`` holds ``(reader, count, k)`` for readers of
+    ``group``, each reader at most once, and the result holds each ask's
+    values.
+
+    The streams are independent, so one set of array operations advances
+    all the sections of one order ``k`` together: the boundary search of
+    :func:`decode_egk` runs over the group's address space, where every
+    lane walks its own stream alone (:func:`_bounds_many`), and the values
+    are read in one pass.  Whether the boundaries are searched by chunk
+    synchronisation or walked is decided by the summed count, as
+    :func:`decode_egk` decides it by its one count.  Each reader's values
+    and cursor afterwards are those of :func:`decode_egk` on that reader
+    alone, and so is the error: an ``EOFError`` of any stream is raised
+    before a ``ValueError`` of any, and after an error no cursor has
+    moved.  An ask with a count of 0 reads nothing; where one stream alone
+    is left, this is :func:`decode_egk` on it.  Spanned as
+    ``golomb.decode`` with ``values`` (the summed count), ``vector``,
+    ``resync_steps``, ``streams`` (the readers advanced) and
+    ``lockstep_values`` (``values`` where more than one reader advanced,
+    else 0).
+    """
+    out = [np.empty(0, np.int64)] * len(asks)
+    live = [j for j, (_, count, _) in enumerate(asks) if count]
+    if len(live) <= 1:
+        for j in live:
+            i, count, k = asks[j]
+            out[j] = decode_egk(group.readers[i], count, k)
+        return out
+    total = sum(asks[j][1] for j in live)
+    vector = total >= _VECTOR_MIN
+    with obs_trace.span("golomb.decode", values=total, vector=int(vector),
+                        streams=len(live), lockstep_values=total) as sp:
+        found, steps = [], 0
+        for k in sorted({asks[j][2] for j in live}):
+            same = [j for j in live if asks[j][2] == k]
+            idx = np.array([asks[j][0] for j in same], np.int64)
+            count = np.array([asks[j][1] for j in same], np.int64)
+            s0 = group.base[idx] + [group.readers[i].tell()
+                                    for i in idx.tolist()]
+            if vector:
+                bounds, st = _bounds_many(group, s0, count, k + 1, idx)
+                steps += st
+            else:
+                bounds = [_bounds_scalar(group.words, s, c, k + 1,
+                                         int(group.ends[i]),
+                                         int(group.last_ones[i]))
+                          for s, c, i in zip(s0.tolist(), count.tolist(),
+                                             idx.tolist())]
+            found.append((same, idx, count, k, bounds))
+        sp.set(resync_steps=steps)
+        for same, idx, count, k, bounds in found:
+            v = _values(group.words,
+                        np.concatenate([b[:-1] for b in bounds]),
+                        np.concatenate([b[1:] for b in bounds]), k)
+            for j, part in zip(same, np.split(v, np.cumsum(count)[:-1])):
+                out[j] = part
+        for same, idx, _, _, bounds in found:
+            for i, b, base in zip(idx.tolist(), bounds,
+                                  group.base[idx].tolist()):
+                group.readers[i].seek(int(b[-1]) - base)
+        return out
 
 
 # sections of at least this many codewords take the chunk synchronisation;
@@ -142,31 +217,31 @@ _ZZ16 = 2 * (16 - np.frexp(np.arange(1 << 16, dtype=np.float64))[1]
 _M64 = (1 << 64) - 1
 
 
-def _bounds_scalar(reader: BitReader, s: int, count: int,
-                   k: int) -> np.ndarray:
-    """The ``count + 1`` boundaries from ``s``, one codeword at a time."""
-    n = reader.nbits
-    item = reader.words().item
-    k1 = k + 1
+def _bounds_scalar(words: np.ndarray, s: int, count: int, k1: int, n: int,
+                   last: int) -> np.ndarray:
+    """The ``count + 1`` boundaries from ``s``, one codeword at a time, in
+    a stream that ends at ``n`` and whose last set bit is at ``last``."""
+    item = words.item
     out = [s]
     for _ in range(count):
         if s >= n:
             raise EOFError("bitstream exhausted")
-        s = _next_scalar(reader, item, s, k1)
+        s = _next_scalar(words, item, s, k1, n, last)
         out.append(s)
     if s > n:
         raise EOFError("bitstream exhausted")
     return np.array(out, np.int64)
 
 
-def _next_scalar(reader: BitReader, item, s: int, k1: int) -> int:
+def _next_scalar(words: np.ndarray, item, s: int, k1: int, n: int,
+                 last: int) -> int:
     """The codeword end after start ``s`` in plain ints: the prefix ends at
     the first set bit of the 64-bit window at ``s`` (``int.bit_length``),
     and the codeword spans twice the prefix plus k + 1."""
     w = (item(s >> 3) << (s & 7)) & _M64
     if w:
         return s + 2 * (64 - w.bit_length()) + k1
-    return int(_far_bounds(reader, np.array([s]), k1)[0])   # 57+ zeros
+    return int(_far_bounds(words, np.array([s]), k1, n, last)[0])   # 57+ zeros
 
 
 def _step(reader: BitReader, p: np.ndarray, zz: np.ndarray,
@@ -183,7 +258,8 @@ def _step(reader: BitReader, p: np.ndarray, zz: np.ndarray,
     q += p
     if not t.all():                         # 16+ leading zeros
         far = np.flatnonzero(t == 0)
-        q[far] = _far_bounds(reader, p[far], k1)
+        q[far] = _far_bounds(reader.words(), p[far], k1, reader.nbits,
+                             reader.last_one())
     return q
 
 
@@ -194,14 +270,16 @@ def _bit_length(w: np.ndarray) -> np.ndarray:
     return np.where(hi > 0, hi + 11, lo).astype(np.int64)
 
 
-def _far_bounds(reader: BitReader, p: np.ndarray, k1: int) -> np.ndarray:
+def _far_bounds(words: np.ndarray, p: np.ndarray, k1: int, n,
+                last) -> np.ndarray:
     """Codeword ends for starts whose first 16 bits are zero: the set bit
-    that ends the prefix is found word by word; a start past the stream's
-    last set bit has none and gets ``n + 1``."""
-    n = reader.nbits
-    words = reader.words()
-    out = np.full(p.size, n + 1, np.int64)
-    live = np.flatnonzero(p <= reader.last_one())
+    that ends the prefix is found word by word; a start past its stream's
+    last set bit ``last`` has none and gets ``n + 1``.  ``n`` and ``last``
+    are one stream's or one per start (starts in the streams of a
+    :class:`~repro.coding.bitstream.ReaderGroup`)."""
+    out = np.zeros(p.size, np.int64)
+    out += n + 1
+    live = np.flatnonzero(p <= last)
     if not live.size:
         return out
     q = p[live]
@@ -216,7 +294,7 @@ def _far_bounds(reader: BitReader, p: np.ndarray, k1: int) -> np.ndarray:
         one[rest[got]] = 8 * byte[rest[got]] + 64 - bl[got]
         byte[rest] += 8
         rest = rest[~got]
-    out[live] = np.minimum(2 * one - q + k1, n + 1)
+    out[live] = np.minimum(2 * one - q + k1, out[live])
     return out
 
 
@@ -296,12 +374,13 @@ def _window(reader: BitReader, s: int, e: int, zz: np.ndarray, k1: int,
         wl.append(lanes)
         wq.append(q)
         q = _step(reader, q, zz, k1)
-    item, tl, tq = reader.words().item, [], []
+    words, n, last = reader.words(), reader.nbits, reader.last_one()
+    item, tl, tq = words.item, [], []
     for i, x in zip(lanes.tolist(), q.tolist()):
         while x < e and not mark[x - s]:
             tl.append(i)
             tq.append(x)
-            x = _next_scalar(reader, item, x, k1)
+            x = _next_scalar(words, item, x, k1, n, last)
         land[i] = x
     wl.append(np.array(tl, np.int64))
     wq.append(np.array(tq, np.int64))
@@ -332,28 +411,182 @@ def _window(reader: BitReader, s: int, e: int, zz: np.ndarray, k1: int,
     return bounds, int(land[last]), int(wq.size)
 
 
-def _values(reader: BitReader, bounds: np.ndarray, k: int) -> np.ndarray:
-    """Every codeword's value from its boundaries: codeword i's first set
-    bit is ``z = (b[i] + b[i + 1] - k - 1) / 2`` and its value bits run
-    from ``z`` to ``b[i + 1]``; they are read from the 64-bit window at
-    ``z``'s byte (plus the next byte where they pass its end).  Raises
-    ``ValueError`` on codewords too long to be well-formed."""
-    if bounds.size < 2:
+def _step_many(group: ReaderGroup, p: np.ndarray, zz: np.ndarray,
+               k1: int) -> np.ndarray:
+    """:func:`_step` for positions in a group's streams: a start with no
+    set bit after it in its stream gets that stream's end + 1."""
+    w = group.words.take(p >> 3)
+    w <<= p & 7
+    t = w.view(np.uint64)
+    t >>= np.uint64(48)
+    q = zz.take(t.view(np.int64))
+    q += p
+    if not t.all():                         # 16+ leading zeros
+        far = np.flatnonzero(t == 0)
+        i = group.stream_of(p[far])
+        q[far] = _far_bounds(group.words, p[far], k1, group.ends[i],
+                             group.last_ones[i])
+    return q
+
+
+def _bounds_many(group: ReaderGroup, s0: np.ndarray, count: np.ndarray,
+                 k1: int, idx: np.ndarray) -> tuple[list[np.ndarray], int]:
+    """:func:`_bounds_vector` for one section of order ``k1 - 1`` in each
+    of the streams ``idx`` of ``group``: each stream's ``count + 1``
+    boundaries from ``s0`` (group positions), and the resync steps taken.
+    Each window (:func:`_window_many`) covers every stream whose
+    boundaries are still short, each stream's part sized as
+    :func:`_bounds_vector` sizes its window."""
+    n = group.ends[idx]
+    last = group.last_ones[idx]
+    chunk = _CHUNK_CODES * k1
+    parts: list[list[np.ndarray]] = [[] for _ in idx]
+    have = np.zeros(idx.size, np.int64)
+    s = s0.copy()
+    per = np.full(idx.size, k1 + 1.25)
+    steps = 0
+    act = np.arange(idx.size)
+    while act.size:
+        if (s[act] > n[act]).any():
+            raise EOFError("bitstream exhausted")
+        sa = s[act]
+        need = count[act] + 1 - have[act]
+        e = np.minimum(sa + (need * per[act]).astype(np.int64) + chunk,
+                       n[act] + 1)
+        got, nxt, st = _window_many(group, sa, e, k1, n[act], last[act])
+        for j, b in zip(act.tolist(), got):
+            parts[j].append(b)
+        have[act] += [b.size for b in got]
+        s[act] = nxt
+        steps += st
+        per[act] = 1.25 * (nxt - s0[act]) / have[act]
+        act = act[have[act] < count[act] + 1]
+    bounds = [(p[0] if len(p) == 1 else np.concatenate(p))[:c + 1]
+              for p, c in zip(parts, count.tolist())]
+    return bounds, steps
+
+
+def _window_many(group: ReaderGroup, s: np.ndarray, e: np.ndarray, k1: int,
+                 n: np.ndarray, last: np.ndarray
+                 ) -> tuple[list[np.ndarray], np.ndarray, int]:
+    """:func:`_window` over one window ``[s[j], e[j])`` in each of several
+    streams of a group: each window's boundaries, each window's first
+    boundary at or past its end, and the resync steps taken.
+
+    The chunks of all windows form one row of paths, and the marks of all
+    windows lie side by side in one array (window j's position ``x`` at
+    slot ``x + d[j]``).  A path walks its own stream only: it resyncs on
+    its own window's marks and stops at its own window's end, so it hands
+    the parse on within its stream, and the chain over paths is resolved
+    from every window's first path at once.
+    """
+    A = s.size
+    chunk = _CHUNK_CODES * k1
+    zz = _ZZ16 + k1
+    J = -(-(e - s) // chunk)
+    first = np.cumsum(J) - J
+    Jt = int(J.sum())
+    sid = np.repeat(np.arange(A), J)        # the window of each path
+    path = np.arange(Jt)
+    size = J * chunk + 1                    # each window's last slot stays
+    d = np.cumsum(size) - size - s          # unmarked
+    sl, el, dl = s[sid], e[sid], d[sid]
+    c = sl + chunk * (path - first[sid])
+    end = np.minimum(c + chunk, el)
+    rows = [c]
+    p = c
+    for _ in range(_CHUNK_CODES):           # codewords are k + 1 bits or more
+        p = _step_many(group, p, zz, k1)
+        rows.append(p)
+    P = np.stack(rows)                      # (steps + 1, Jt), rising by column
+    own = P < end
+    Pm = P + dl                             # the marks' slots
+    mark = np.zeros(int(size.sum()), bool)
+    mark[Pm[own]] = True
+
+    # resync: every path from its exit until it lands on a mark of its own
+    # window or leaves it (past its window's end a slot may be another
+    # window's: the end test decides there)
+    top = mark.size - 1
+    lanes = path
+    q = P[own.sum(0), lanes]
+    land = np.empty(Jt, np.int64)
+    wl, wq = [], []
+    while lanes.size > _RESYNC_TAIL:
+        done = mark[np.minimum(q + dl[lanes], top)]
+        done |= q >= el[lanes]
+        land[lanes[done]] = q[done]
+        keep = ~done
+        lanes, q = lanes[keep], q[keep]
+        wl.append(lanes)
+        wq.append(q)
+        q = _step_many(group, q, zz, k1)
+    words = group.words
+    item, tl, tq = words.item, [], []
+    for i, x in zip(lanes.tolist(), q.tolist()):
+        j = int(sid[i])
+        ej, dj, nj, lj = int(e[j]), int(d[j]), int(n[j]), int(last[j])
+        while x < ej and not mark[x + dj]:
+            tl.append(i)
+            tq.append(x)
+            x = _next_scalar(words, item, x, k1, nj, lj)
+        land[i] = x
+    wl.append(np.array(tl, np.int64))
+    wq.append(np.array(tq, np.int64))
+
+    # the chain over paths, from every window's first path at once
+    nxt = np.append(np.where(land < el, first[sid] + (land - sl) // chunk,
+                             Jt), Jt)
+    seq, f = first[None, :], nxt
+    while (seq[-1] != Jt).any():
+        seq = np.concatenate([seq, f[seq]])
+        f = f[f]
+    on = np.zeros(Jt + 1, bool)
+    on[seq] = True
+    on = on[:Jt]
+    entry = c.copy()
+    src = np.flatnonzero(on & (nxt[:Jt] < Jt))
+    entry[nxt[src]] = land[src]
+    # the true parse, in order without a sort: the marks of the followed
+    # paths from their entries on (every other mark is cleared; no two
+    # paths mark one slot) and their resync steps (never marked)
+    followed = P >= entry
+    followed &= on
+    own &= ~followed
+    mark[Pm[own]] = False
+    wl, wq = np.concatenate(wl), np.concatenate(wq)
+    walked = on[wl]
+    mark[wq[walked] + dl[wl[walked]]] = True
+    slots = np.flatnonzero(mark)
+    cut = np.searchsorted(slots, np.cumsum(size)[:-1]).tolist()
+    got = [b - dj for b, dj in zip(np.split(slots, cut), d.tolist())]
+    lastp = seq[(seq != Jt).sum(0) - 1, np.arange(A)]
+    return got, land[lastp], int(walked.sum())
+
+
+def _values(words: np.ndarray, starts: np.ndarray, ends: np.ndarray,
+            k: int) -> np.ndarray:
+    """Every codeword's value from where it starts and ends: codeword i's
+    first set bit is ``z = (starts[i] + ends[i] - k - 1) / 2`` and its
+    value bits run from ``z`` to ``ends[i]``; they are read from the 64-bit
+    window at ``z``'s byte (plus the next byte where they pass its end).
+    Raises ``ValueError`` on
+    codewords too long to be well-formed."""
+    if starts.size == 0:
         return np.zeros(0, np.int64)
-    t = bounds[1:]
-    z = (bounds[:-1] + t - (k + 1)) >> 1
-    nb = t - z
+    z = (starts + ends - (k + 1)) >> 1
+    nb = ends - z
     top = int(nb.max())
     if top > _MAX_CODE_BITS:
         raise ValueError(f"exp-Golomb codeword of {top} bits (corrupt)")
     byte = z >> 3
     off = z & 7
-    w = reader.words()[byte]
+    w = words[byte]
     w <<= off
     w = w.view(np.uint64)
     if top > 57:
         far = np.flatnonzero(off + nb > 64)
-        nxt = reader.words()[byte[far] + 8].view(np.uint64)
+        nxt = words[byte[far] + 8].view(np.uint64)
         w[far] |= nxt >> (64 - off[far]).view(np.uint64)
     w >>= (64 - nb).view(np.uint64)
     v = w.view(np.int64)
@@ -389,7 +622,8 @@ def decode_egk_jump(reader: BitReader, count: int, k: int) -> np.ndarray:
     """
     if count < _JUMP_MIN:
         return decode_egk(reader, count, k)
-    with obs_trace.span("golomb.decode", values=count):
+    with obs_trace.span("golomb.decode", values=count, streams=1,
+                        lockstep_values=0):
         return _decode_egk_orbit(reader, count, k)
 
 
@@ -427,6 +661,6 @@ def _decode_egk_orbit(reader: BitReader, count: int, k: int) -> np.ndarray:
     s = int(starts[-1])
     if s > n:
         raise EOFError("bitstream exhausted")
-    v = _values(reader, starts, k)
+    v = _values(reader.words(), starts[:-1], starts[1:], k)
     reader.seek(s)
     return v

@@ -45,6 +45,10 @@ headers, range-decoder overrun, and framing-invariant violations raise
 or escaping as ``IndexError``.  ``encode_tree_batch``/``decode_tree_batch``
 code a whole cohort of messages against ONE shared shapes view (paths
 formatted and sorted once) — the host half of the batched uplink API.
+Under the vectorized engine ``decode_tree_batch`` decodes two or more
+messages in lockstep: tensor by tensor, each message's bins and headers
+in its own order, and each exp-Golomb section parsed for all of them at
+once (``golomb.decode_egk_many``).
 """
 from __future__ import annotations
 
@@ -53,7 +57,7 @@ from typing import Any, Sequence
 import numpy as np
 
 from repro.coding import golomb
-from repro.coding.bitstream import BitReader, BitWriter
+from repro.coding.bitstream import BitReader, BitWriter, ReaderGroup
 from repro.obs import trace as obs_trace
 from repro.coding.cabac import (ContextSet, Decoder, Encoder,
                                 encode_context_bins)
@@ -272,6 +276,24 @@ def decode_tensor(shape: tuple, enc_dec: Decoder, ctx: ContextSet,
     under ``jump=True`` (the speculative engine) via the pointer-doubling
     boundary walk."""
     egk = golomb.decode_egk_jump if jump else golomb.decode_egk
+    steps = _tensor_steps(shape, enc_dec, ctx, bypass)
+    values = None
+    while True:
+        try:
+            count, k = steps.send(values)
+        except StopIteration as done:
+            return done.value
+        values = egk(bypass, count, k)
+
+
+def _tensor_steps(shape: tuple, enc_dec: Decoder, ctx: ContextSet,
+                  bypass: BitReader):
+    """One tensor's decode, less its exp-Golomb parses: yields each
+    section as ``(count, k)`` when the bypass cursor stands at its start,
+    is sent its values, and returns the tensor.  The caller parses the
+    section and leaves the cursor at its end — one message at a time
+    (:func:`decode_tensor`) or the same section of a batch's messages
+    together (:func:`_decode_tensors_lockstep`)."""
     ndim = len(shape)
     size = int(np.prod(shape)) if shape else 1
     m = shape[0] if ndim >= 2 else 1
@@ -288,7 +310,7 @@ def decode_tensor(shape: tuple, enc_dec: Decoder, ctx: ContextSet,
     kept = np.zeros(kept_len, np.int64)
     if nnz > 0:
         k_run = bypass.get_uint(4)
-        gaps = egk(bypass, nnz, k_run)
+        gaps = yield nnz, k_run
         idx = np.cumsum(gaps + 1) - 1
         _check_positions(idx, kept_len)
         signs = bypass.get_bits(nnz).astype(np.int64)
@@ -301,11 +323,37 @@ def decode_tensor(shape: tuple, enc_dec: Decoder, ctx: ContextSet,
         k_rem = bypass.get_uint(4)  # always framed when nnz>0
         _check_k_rem(k_rem, n2)
         if n2:
-            rem = egk(bypass, n2, k_rem)
+            rem = yield n2, k_rem
             mg1[gt2] = rem + 3
         mags[gt1] = mg1
         kept[idx] = np.where(signs == 1, -mags, mags)
     return _reassemble(shape, m, row_len, nz_rows, kept)
+
+
+def _decode_tensors_lockstep(shape: tuple, decs: list[Decoder],
+                             ctxs: list[ContextSet], readers: list[BitReader],
+                             group: ReaderGroup) -> list[np.ndarray]:
+    """The same tensor from each message of a batch: every message runs
+    its own :func:`_tensor_steps` (its bins, headers and signs in its own
+    order), and the section each stands at is parsed for all of them in
+    one ``golomb.decode_egk_many`` call — the gap sections together, then
+    the remainder sections."""
+    steps = [_tensor_steps(shape, d, c, r)
+             for d, c, r in zip(decs, ctxs, readers)]
+    out: list = [None] * len(steps)
+    sent: list = [None] * len(steps)
+    waiting = range(len(steps))
+    while waiting:
+        asks = []
+        for i in waiting:
+            try:
+                asks.append((i, *steps[i].send(sent[i])))
+            except StopIteration as done:
+                out[i] = done.value
+        for (i, _, _), v in zip(asks, golomb.decode_egk_many(group, asks)):
+            sent[i] = v
+        waiting = [i for i, _, _ in asks]
+    return out
 
 
 # ---------------------------------------------------------------- validation
@@ -404,6 +452,43 @@ def _decode_sections_inner(data: bytes, path_shapes: list[tuple[str, tuple]],
         raise
     except _DECODE_ERRORS as e:
         raise CorruptPayloadError(f"payload failed to decode: {e}") from e
+    _check_consumed(dec, cab, bypass)
+    return decoded
+
+
+def _decode_lockstep(datas: Sequence[bytes],
+                     path_shapes: list[tuple[str, tuple]]
+                     ) -> list[dict[str, np.ndarray]]:
+    """Decode a batch of messages tensor by tensor, each exp-Golomb
+    section parsed for every message at once
+    (:func:`_decode_tensors_lockstep`).  Each message keeps its own CABAC
+    decoder, contexts and bypass reader, and is validated as
+    :func:`_decode_sections_inner` validates it; any message's failure
+    raises ``CorruptPayloadError`` for the batch."""
+    with obs_trace.span("nnc.decode", nbytes=sum(len(d) for d in datas),
+                        messages=len(datas)):
+        frames = [_split_frame(d) for d in datas]
+        decs = [Decoder(cab, strict=True) for cab, _ in frames]
+        ctxs = [ContextSet(NUM_CTX) for _ in frames]
+        readers = [BitReader(byp) for _, byp in frames]
+        decoded: list[dict[str, np.ndarray]] = [{} for _ in frames]
+        try:
+            group = ReaderGroup(readers)
+            for path, shape in path_shapes:
+                tensors = _decode_tensors_lockstep(shape, decs, ctxs,
+                                                   readers, group)
+                for out, t in zip(decoded, tensors):
+                    out[path] = t
+        except CorruptPayloadError:
+            raise
+        except _DECODE_ERRORS as e:
+            raise CorruptPayloadError(f"payload failed to decode: {e}") from e
+        for dec, (cab, _), bypass in zip(decs, frames, readers):
+            _check_consumed(dec, cab, bypass)
+        return decoded
+
+
+def _check_consumed(dec: Decoder, cab: bytes, bypass: BitReader) -> None:
     # a well-formed message is consumed exactly: the cabac stream to the
     # byte, the bypass stream to within its <8 padding bits — leftovers
     # prove the shapes tree does not match the encoder's
@@ -415,7 +500,6 @@ def _decode_sections_inner(data: bytes, path_shapes: list[tuple[str, tuple]],
         raise CorruptPayloadError(
             f"{bypass.bits_remaining} unread bypass bits (shapes tree "
             "does not match the message)")
-    return decoded
 
 
 def _shape_items(shapes_tree: Any):
@@ -539,9 +623,18 @@ def _batch_leaf_order(tree: Any) -> list[int]:
 
 def decode_tree_batch(payloads: Sequence[bytes], shapes_tree: Any,
                       engine: str = DEFAULT_ENGINE) -> list[Any]:
-    """Decode K payloads against ONE shared shapes view (parsed once)."""
+    """Decode K payloads against ONE shared shapes view (parsed once).
+
+    Under the ``vectorized`` engine two or more payloads decode in
+    lockstep (:func:`_decode_lockstep`): each exp-Golomb section is
+    parsed for all of them at once, and any payload's failure raises
+    ``CorruptPayloadError`` for the batch.  One payload, and the
+    ``serial`` and ``speculative`` engines, decode message by message.
+    Result i equals ``decode_tree(payloads[i], shapes_tree, engine)``."""
     _check_engine(engine)
     items, cache = _shape_items(shapes_tree)
+    if engine == "vectorized" and len(payloads) > 1:
+        return [_rebuild(d, cache) for d in _decode_lockstep(payloads, items)]
     return [_rebuild(_decode_sections(p, items, engine), cache)
             for p in payloads]
 

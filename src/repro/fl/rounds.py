@@ -404,13 +404,14 @@ class Uplink:
     released), ``"process"`` for the entropy coders.  Results come back in
     submission order — parallelism cannot change bytes.
 
-    ``uplink_batch=True`` swaps the per-client dispatch for the codec
-    **batch API**: the cohort splits into at most ``workers`` contiguous
-    chunks, ONE pool task per chunk (``pool_tasks`` counts submissions),
-    all messages coded against one shared shapes view, and process
-    workers return ``comms.FlatDecoded`` flat arrays instead of pickled
-    pytrees — the host reassembles them against its own spec.  Payloads
-    are byte-identical to the per-client path.
+    With more than one worker, ``uplink_batch=True`` swaps the per-client
+    dispatch for the codec **batch API**: the cohort splits into at most
+    ``workers`` contiguous chunks, ONE pool task per chunk (``pool_tasks``
+    counts submissions), all messages coded against one shared shapes
+    view, and process workers return ``comms.FlatDecoded`` flat arrays
+    instead of pickled pytrees — the host reassembles them against its own
+    spec.  Payloads are byte-identical to the per-client path.  With at
+    most one worker the intake always takes the batch API, in this thread.
     """
 
     def __init__(self, cfg: ProtocolConfig, engine_cfg, server: ServerState):
@@ -564,6 +565,10 @@ class Uplink:
         """Encode+decode every update; parallel across clients when
         configured (order-preserving either way).
 
+        With at most one worker the whole cohort goes through the codec's
+        batch API in this thread: every update encoded, then all payloads
+        decoded in one ``decode_batch`` (the nnc-cabac codec parses the
+        same section of every message together).  With more workers,
         ``uplink_batch=False`` is the per-client dispatch: one executor
         task per update.  ``uplink_batch=True`` routes through the codec's
         batch API — the cohort splits into at most ``workers`` contiguous
@@ -571,9 +576,9 @@ class Uplink:
         costs <= W submissions instead of K, and process workers return
         flat arrays instead of pickled pytrees.  Either way results come
         back in submission order — parallelism cannot change bytes."""
+        if self.workers <= 1 or len(upds) <= 1:
+            return self._roundtrip_batch(upds, clients)
         if not self.batch:
-            if self.workers <= 1 or len(upds) <= 1:
-                return [self._roundtrip(u) for u in upds]
             fn = (self._roundtrip if self.executor_kind == "thread"
                   else _pool_roundtrip)
             self.pool_tasks += len(upds)
@@ -585,8 +590,6 @@ class Uplink:
         # weaken the no-duplicate check (a duplicate pair could otherwise
         # land in different chunks and pass per-chunk validation)
         comms.check_batch_clients(clients, len(upds), "updates")
-        if self.workers <= 1 or len(upds) <= 1:
-            return self._roundtrip_batch(upds, clients)
         nchunks = min(self.workers, len(upds))
         bounds = np.array_split(np.arange(len(upds)), nchunks)
         chunks = [([upds[i] for i in b],

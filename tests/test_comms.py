@@ -10,6 +10,7 @@ import numpy as np
 import pytest
 
 from repro import comms
+from repro.coding import golomb, nnc
 from repro.core import fsfl as fsfl_lib
 from repro.core import quant as quant_lib
 from repro.core.protocol import ProtocolConfig
@@ -17,6 +18,7 @@ from repro.data import federated, synthetic
 from repro.fl import Scenario, run_scenario
 from repro.fl.engine import EngineConfig, encode_client_bytes, run_simulation
 from repro.models import cnn
+from repro.obs import trace as obs_trace
 
 # lossy wire error bounds: fp16 = relative rounding, int8 = amax/254 per
 # block (half a quantization step), both plus float slack
@@ -26,8 +28,9 @@ LOSSY_ATOL = {"fp16": lambda amax: 1e-6 + amax * 5e-4,
 
 # ------------------------------------------------------------- fixtures
 
-def _random_update(seed, ternary=False, shapes=None):
-    """A consistent (levels, recon) update + spec on a small mixed tree."""
+def _random_update(seed, ternary=False, shapes=None, density=0.25):
+    """A consistent (levels, recon) update + spec on a small mixed tree
+    (or on ``shapes``), ``density`` of its levels nonzero."""
     rng = np.random.default_rng(seed)
     shapes = shapes or {"conv": {"w": (6, 4, 3, 3), "b": (6,)},
                         "fc": {"w": (5, 24)}}
@@ -53,7 +56,7 @@ def _random_update(seed, ternary=False, shapes=None):
     else:
         lv = tree_of(
             lambda s: (rng.integers(-40, 41, s)
-                       * (rng.random(s) < 0.25)).astype(np.int32), shapes)
+                       * (rng.random(s) < density)).astype(np.int32), shapes)
         recon = jax.tree.map(
             lambda l, f: l.astype(np.float32)
             * np.float32(q.fine_step_size if f else q.step_size), lv, fine)
@@ -330,6 +333,86 @@ if HAVE_HYP:
             amax = float(np.max(np.abs(recon))) if recon.size else 0.0
             np.testing.assert_allclose(dec.params["w"], recon,
                                        atol=max(amax / 250.0, 1e-7))
+
+
+# ------------------------------------------------------------- batch decode
+
+@pytest.fixture(scope="module")
+def vgg_cohort():
+    """Ten fsfl-like updates (10% of the levels nonzero) at the thinned
+    VGG-11's widths, and their nnc-cabac payloads."""
+    params, _ = jax.eval_shape(cnn.vgg11_thinned().init,
+                               jax.random.PRNGKey(0))
+    shapes = jax.tree.map(lambda s: tuple(s.shape), params)
+    codec = comms.get_codec("nnc-cabac")
+    upds, spec = [], None
+    for i in range(10):
+        u, spec = _random_update(700 + i, shapes=shapes, density=0.1)
+        upds.append(u)
+    return codec, spec, codec.encode_batch(upds, spec)
+
+
+def _msg_shapes(codec, spec):
+    return codec._msg_shapes(
+        [(p, tuple(s.shape)) for p, s in spec.param_items()],
+        [(p, tuple(s.shape)) for p, s in spec.scale_items()])
+
+
+@pytest.mark.parametrize("K", [1, 2, 10])
+def test_batch_decode_equals_per_message_decode(vgg_cohort, K):
+    """``nnc.decode_tree_batch`` and ``NncCabacCodec.decode_batch`` over K
+    messages equal the per-message ``decode_tree`` / ``decode``, leaf for
+    leaf.  Two or more messages decode in lockstep: one ``nnc.decode``
+    span for the batch, and each exp-Golomb section of several messages
+    parsed in one ``golomb.decode`` span (every large one with all K
+    streams); one message decodes alone, as before."""
+    codec, spec, payloads = vgg_cohort
+    batch = payloads[:K]
+    shapes = _msg_shapes(codec, spec)
+    rec = obs_trace.SpanRecorder()
+    with obs_trace.use_recorder(rec):
+        decs = codec.decode_batch(batch, spec)
+        trees = nnc.decode_tree_batch(batch, shapes)
+    for p, dec, tree in zip(batch, decs, trees):
+        one = codec.decode(p, spec)
+        for a, b in zip(jax.tree.leaves((one.params, one.scales)),
+                        jax.tree.leaves((dec.params, dec.scales))):
+            np.testing.assert_array_equal(a, b)
+        for a, b in zip(jax.tree.leaves(nnc.decode_tree(p, shapes)),
+                        jax.tree.leaves(tree)):
+            np.testing.assert_array_equal(a, b)
+    msgs = [s.attrs for s in rec.snapshot() if s.name == "nnc.decode"]
+    parses = [s.attrs for s in rec.snapshot() if s.name == "golomb.decode"]
+    if K == 1:
+        assert len(msgs) == 2 and all("messages" not in a for a in msgs)
+        assert all(a["streams"] == 1 and a["lockstep_values"] == 0
+                   for a in parses)
+        return
+    assert [a["messages"] for a in msgs] == [K, K]
+    assert [a["nbytes"] for a in msgs] == [sum(map(len, batch))] * 2
+    for a in parses:
+        assert a["lockstep_values"] == (a["values"] if a["streams"] > 1
+                                        else 0)
+    large = [a for a in parses if a["values"] >= K * golomb._VECTOR_MIN]
+    assert large and all(a["streams"] == K for a in large)
+
+
+def test_batch_decode_with_one_corrupt_payload_raises(vgg_cohort):
+    """A payload whose bypass stream is zeroed after its first gap header
+    passes the frame checks and fails inside the lockstep parse: the
+    batch raises ``CorruptPayloadError``, as that payload alone does."""
+    codec, spec, payloads = vgg_cohort
+    bad = bytearray(payloads[2])
+    byp = 16 + int.from_bytes(bad[:8], "big")
+    bad[byp + 5:] = bytes(len(bad) - byp - 5)
+    batch = payloads[:2] + [bytes(bad)] + payloads[3:5]
+    with pytest.raises(comms.CorruptPayloadError):
+        codec.decode(batch[2], spec)
+    with pytest.raises(comms.CorruptPayloadError):
+        codec.decode_batch(batch, spec)
+    with pytest.raises(comms.CorruptPayloadError) as exc:
+        nnc.decode_tree_batch(batch, _msg_shapes(codec, spec))
+    assert isinstance(exc.value.__cause__, EOFError)
 
 
 # ------------------------------------------------------------- channel

@@ -10,15 +10,18 @@ zero prefixes run longer than a chunk, and whose value bits run past a
 64-bit window.  The error surface is the
 walk's: ``EOFError`` on a section that runs past the stream, ``ValueError``
 on a codeword of more than 63 value bits, and ``CorruptPayloadError``
-through ``nnc.decode_tree``.
+through ``nnc.decode_tree``.  The lockstep parse of several streams
+(``golomb.decode_egk_many``) is held to the same reference on each stream
+alone.
 """
 import numpy as np
 import pytest
 
 from repro.coding import golomb, nnc
-from repro.coding.bitstream import BitReader, BitWriter
+from repro.coding.bitstream import BitReader, BitWriter, ReaderGroup
 from repro.coding.errors import CorruptPayloadError
 from repro.obs import make_telemetry
+from repro.obs import trace as obs_trace
 
 VMIN = golomb._VECTOR_MIN
 
@@ -140,6 +143,89 @@ def test_consecutive_sections_share_one_reader():
         golomb.decode_egk_ref(ref, n, k)
         assert fast.tell() == ref.tell()
         assert fast.get_uint(4) == ref.get_uint(4)
+
+
+# ------------------------------------------------------------- lockstep
+
+# (kind, count, k, lead-in bits or None for a section that ends the
+# stream, trailing bits); "trunc" is a geom section cut in half, "overlong"
+# one with a codeword of 64 value bits
+MANY = {
+    "one": [("geom", 3000, 3, 5, 40)],
+    "two": [("geom", 3000, 3, 5, 40), ("geom", 2200, 3, 0, 9)],
+    "three-orders": [("geom", 3000, 0, 3, 11), ("geom", 2000, 5, 6, 0),
+                     ("wide", 1500, 2, 1, 30)],
+    "ten": [("geom", c, k, i % 8, 17) for i, (c, k) in enumerate(
+        [(1600, 3), (14700, 3), (5000, 4), (2500, 3), (9000, 2),
+         (1700, 3), (3300, 4), (12000, 3), (4100, 2), (2000, 3)])],
+    "zero-counts": [("geom", 0, 3, 4, 8), ("geom", 2500, 3, 2, 9),
+                    ("geom", 0, 1, 0, 0), ("geom", 1800, 3, 7, 1)],
+    "small-sum-over": [("geom", 200, 1 + i % 3, i % 8, 13)
+                       for i in range(10)],
+    "small-sum-under": [("geom", 50, k, 3, 5) for k in (0, 2, 4)],
+    "long-prefixes": [("long", 2000, k, k, 21) for k in (0, 1, 2)],
+    "huge-values": [("huge", 1500, 0, 0, 7), ("huge", 1500, 9, 5, 7),
+                    ("geom", 900, 9, 1, 3)],
+    "end-exact": [("geom", 2500, 3, None, 0), ("zeros", 2500, 0, None, 0),
+                  ("geom", 300, 7, None, 0)],
+    "truncated": [("geom", 2500, 3, 5, 9), ("trunc", 2000, 3, 2, 0),
+                  ("geom", 1800, 3, 0, 4)],
+    "overlong": [("geom", 2500, 2, 5, 9), ("overlong", 1700, 2, 0, 0),
+                 ("geom", 1800, 2, 0, 4)],
+    "eof-before-value-error": [("overlong", 1700, 2, 0, 0),
+                               ("trunc", 2000, 2, 2, 0)],
+}
+MANY_RAISES = {"truncated": EOFError, "overlong": ValueError,
+               "eof-before-value-error": EOFError}
+
+
+def _stream(kind, count, k, pre, tail, seed):
+    """(bytes, lead-in) of one stream holding one section."""
+    if kind == "overlong":
+        return _long_codeword_stream(count, k, count // 2, 64)[0], 0
+    vals = _values("geom" if kind == "trunc" else kind, count, k, seed)
+    if pre is None:
+        pre = _end_exact_pre(vals, k)
+    data = _section(vals, k, pre, tail, seed=seed)
+    return (data[:len(data) // 2] if kind == "trunc" else data), pre
+
+
+@pytest.mark.parametrize("case", list(MANY))
+def test_lockstep_parse_matches_each_stream_alone(case):
+    """One section of each of K streams parsed together gives each
+    stream's values and cursor of the reference on that stream alone,
+    whatever each stream's order, count or position; the batch raises the
+    error a failing stream alone raises (EOFError before ValueError) and
+    then moves no cursor.  The ``golomb.decode`` span counts the streams
+    advanced and, where there are several, their values as lockstep."""
+    plan = MANY[case]
+    streams = [_stream(*spec, seed=31 * i + spec[1])
+               for i, spec in enumerate(plan)]
+    readers = [BitReader(d) for d, _ in streams]
+    for r, (_, pre) in zip(readers, streams):
+        r.seek(pre)
+    asks = [(i, spec[1], spec[2]) for i, spec in enumerate(plan)]
+    rec = obs_trace.SpanRecorder()
+    with obs_trace.use_recorder(rec):
+        if case in MANY_RAISES:
+            with pytest.raises(MANY_RAISES[case]):
+                golomb.decode_egk_many(ReaderGroup(readers), asks)
+            assert [r.tell() for r in readers] == [p for _, p in streams]
+            return
+        got = golomb.decode_egk_many(ReaderGroup(readers), asks)
+    for (data, pre), (_, count, k), v, r in zip(streams, asks, got,
+                                                readers):
+        ref = BitReader(data)
+        ref.seek(pre)
+        np.testing.assert_array_equal(v, golomb.decode_egk_ref(ref, count, k))
+        assert r.tell() == ref.tell()
+    counts = [c for _, c, _ in asks if c]
+    spans = [s.attrs for s in rec.snapshot() if s.name == "golomb.decode"]
+    assert [(a["values"], a["streams"], a["lockstep_values"],
+             a["vector"]) for a in spans] == (
+        [(sum(counts), len(counts), sum(counts),
+          int(sum(counts) >= VMIN))] if len(counts) > 1
+        else [(c, 1, 0, int(c >= VMIN)) for c in counts])
 
 
 # ------------------------------------------------------------- errors

@@ -190,6 +190,30 @@ def test_corrupt_payload_quarantined_rest_of_cohort_aggregates():
         np.testing.assert_array_equal(a, b)
 
 
+def test_parse_failure_in_a_lockstep_chunk_quarantines_only_its_payload():
+    """A payload whose bypass stream is zeroed after its first gap header
+    passes the frame checks and fails inside its chunk's lockstep decode;
+    the chunk is decoded again one payload at a time, so only that payload
+    is rejected and the other seven aggregate."""
+    codec, payloads, spec, decs = _cohort(8, seed=8)
+    bad = list(payloads)
+    body = bytearray(bad[5])
+    byp = 16 + int.from_bytes(body[:8], "big")
+    body[byp + 5:] = bytes(len(body) - byp - 5)
+    bad[5] = bytes(body)
+    with pytest.raises(comms.CorruptPayloadError):
+        codec.decode_batch(bad[4:], spec)
+    res = _ingest_all(codec, bad, spec, IngestConfig(chunk=4))
+    assert res.accepted == 7
+    assert [(r.seq, r.client) for r in res.rejected] == [(5, 5)]
+    assert "CorruptPayloadError" in res.rejected[0].error
+    keep = [d.params for i, d in enumerate(decs) if i != 5]
+    gather = weighted_mean_trees(keep, np.ones(7))
+    for a, b in zip(jax.tree.leaves(res.delta_params),
+                    jax.tree.leaves(gather)):
+        np.testing.assert_array_equal(a, b)
+
+
 def test_header_corruption_quarantined_on_threaded_ingest():
     codec, payloads, spec, _ = _cohort(8, seed=9)
     bad = list(payloads)

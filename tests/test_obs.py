@@ -260,7 +260,7 @@ def test_async_windows_trace_and_batch_histogram(tiny2):
     assert isinstance(tel, Telemetry)
     names = {s.name for s in tel.recorder.snapshot()}
     assert "local_train.window" in names
-    assert "uplink.roundtrip" in names
+    assert "uplink.roundtrip_batch" in names
     hist = res.records[-1].telemetry["histograms"].get("async.batch_size")
     assert hist is not None and hist["count"] >= 1
 

@@ -335,6 +335,46 @@ def test_batched_uplink_chunks_cohort_into_at_most_worker_tasks(tiny8):
     assert batch.records[0].test_acc == base.records[0].test_acc
 
 
+def test_gather_intake_decodes_the_cohort_together(tiny8):
+    """At ``uplink_workers`` 0 the gather intake encodes the cohort, then
+    decodes it in one batch: the same payload bytes and decoded
+    contributions, in client order, as one ``_roundtrip`` per client, and
+    each exp-Golomb section of the eight messages is parsed together
+    (``golomb.decode`` spans with ``streams`` = 8, all values lockstep)."""
+    model, splits = tiny8
+    cfg = ProtocolConfig(name="fsfl", method="sparse", fixed_sparsity=0.9,
+                         batch_size=32, local_lr=2e-3)
+    eng = FederatedEngine(model, cfg, splits, jax.random.PRNGKey(7),
+                          engine_cfg=EngineConfig(telemetry="trace"))
+    up = eng.uplink
+    calls = []
+    orig = up.roundtrip_all
+
+    def spy(upds, clients=None):
+        out = orig(upds, clients)
+        calls.append((upds, clients, out))
+        return out
+
+    up.roundtrip_all = spy
+    res = eng.run(1)
+    [(upds, clients, got)] = calls
+    assert clients == list(range(8))
+    assert (up.codec.encode_batch(upds, up.spec)
+            == [up.codec.encode(u, up.spec) for u in upds])
+    per = [up._roundtrip(u) for u in upds]
+    assert [n for n, _ in got] == [n for n, _ in per]
+    assert res.records[0].up_bytes == sum(n for n, _ in per)
+    for (_, a), (_, b) in zip(got, per):
+        for x, y in zip(jax.tree.leaves((a.params, a.scales)),
+                        jax.tree.leaves((b.params, b.scales))):
+            np.testing.assert_array_equal(np.asarray(x), np.asarray(y))
+    parses = [s.attrs for s in res.telemetry.recorder.snapshot()
+              if s.name == "golomb.decode"]
+    assert parses and all(a["streams"] == 8
+                          and a["lockstep_values"] == a["values"]
+                          for a in parses)
+
+
 def test_batched_uplink_forkserver_contributions_equal_serial(tiny2):
     """The flat-array transport (no pytree pickling) through the forkserver
     pool reassembles bitwise-identical Contributions."""
